@@ -59,11 +59,11 @@ sync``; KV write-back one step deferred, mirrors double-buffered)
 against the synchronous ``decode()`` wrapper, twin real-LM backends
 serving identical ragged lanes in single-pool, 2-shard, and tiered
 configurations.  Decode runs a genuinely compiled path — the Pallas
-kernel non-interpret where the jax backend supports it, else the jitted
-XLA gather decode (CPU Pallas only runs interpreted, which is not a
-wall-clock measurement).  Greedy tokens must be bit-identical; the
-derived column is 100 * t_sequential / t_pipelined (>= 100: the
-pipeline at least matches sequential step throughput).
+kernel on a TPU, else the jitted XLA gather decode (CPU Pallas only
+runs interpreted, which is not a wall-clock measurement).  Greedy
+tokens must be bit-identical; the derived column is 100 * t_sequential
+/ t_pipelined (>= 100: the pipeline at least matches sequential step
+throughput).
 
 Traffic-class section (``kvcache/sched/class/...``): SMS staged
 scheduling + decode preemption under overload
@@ -603,10 +603,10 @@ def decode_pipeline_comparison(scenario: str = "single", *,
     ``scenario``: "single" (one pool), "shards2" (mesh-sharded, 2
     shards, issue-then-gather dispatch), "tiered" (spill tiers behind
     the pool).  The decode path is compiled, never interpreted: the
-    Pallas kernel with ``kernel_interpret=False`` on TPU/GPU, the jitted
-    XLA gather decode on CPU (where Pallas supports interpret mode
-    only).  Prompt lengths and step counts stay inside one pow2 operand
-    bucket so neither loop recompiles mid-flight.
+    Pallas kernel on a TPU, the jitted XLA gather decode on CPU (where
+    Pallas supports interpret mode only).  Prompt lengths and step
+    counts stay inside one pow2 operand bucket so neither loop
+    recompiles mid-flight.
 
     Returns ``{"seq_us", "pipe_us", "ratio"}`` — best per-step wall
     times and ``100 * t_seq / t_pipe`` (>= 100 means the pipeline at
@@ -622,10 +622,9 @@ def decode_pipeline_comparison(scenario: str = "single", *,
     import jax
     from repro.kvcache.backend import make_backend
 
-    mode = "kernel" if jax.default_backend() in ("tpu", "gpu") else "gather"
+    mode = "kernel" if jax.default_backend() == "tpu" else "gather"
     cfg, params = _pipeline_model(seed)
-    kw = dict(num_blocks=64, block_size=16, decode_mode=mode,
-              kernel_interpret=False)
+    kw = dict(num_blocks=64, block_size=16, decode_mode=mode)
     if scenario == "shards2":
         kw["shards"] = 2
     elif scenario == "tiered":
@@ -708,14 +707,13 @@ def mixed_traffic_comparison(scenario: str = "single", *,
 
     Tokens are greedy over fixed params, the clock is the step counter,
     and the schedule is seeded, so both ratios are deterministic."""
-    import jax  # noqa: F401  (backend selection side effects)
+    import jax
     from repro.kvcache.backend import make_backend
     from repro.serve.engine import PagedLM, ServeEngine
     from repro.serving.scheduler import MarsScheduler, Request, \
         default_classes
 
-    mode = "kernel" if __import__("jax").default_backend() \
-        in ("tpu", "gpu") else "gather"
+    mode = "kernel" if jax.default_backend() == "tpu" else "gather"
     cfg, params = _pipeline_model(seed)
     rng = np.random.default_rng(seed)
     prefixes = [tuple(int(t) for t in rng.integers(1, cfg.vocab, 16))
@@ -736,8 +734,7 @@ def mixed_traffic_comparison(scenario: str = "single", *,
         spec.append(("interactive", p, 4.0 + 2 * i, 4))
 
     def serve(classes) -> dict:
-        kw = dict(num_blocks=16, block_size=16, decode_mode=mode,
-                  kernel_interpret=False)
+        kw = dict(num_blocks=16, block_size=16, decode_mode=mode)
         if scenario == "shards2":
             # 12 blocks/shard: a sequence never spans shards, so per-shard
             # pressure must stay comparable to the single-pool run for

@@ -9,7 +9,8 @@ All three MARS layers of the serving stack:
      prefix-shared blocks, MARS-aware placement, copy-on-write forks,
      pool-capacity admission;
   3. the BULK kernel: paged_attention reading the pool's block tables
-     (Pallas interpret mode), validated against the dense jnp oracle;
+     (interpreted on the CPU, compiled on a TPU), validated against the
+     dense jnp oracle;
   4. the FULL LM: a real multi-layer config served through the unified
      KV-backend API (``PagedBackend``), token-exact against the dense
      backend.

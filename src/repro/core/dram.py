@@ -129,7 +129,7 @@ class _ChState(NamedTuple):
     t_end: jnp.ndarray       # int32 latest data end
 
 
-_BIG = jnp.int32(1 << 29)
+_BIG = np.int32(1 << 29)   # numpy: importing must not start a jax backend
 
 
 @functools.partial(jax.jit, static_argnums=(2, 3))

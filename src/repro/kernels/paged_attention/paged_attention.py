@@ -42,6 +42,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import pallas_interpret
+
 NEG_INF = -1e30
 
 
@@ -79,28 +81,36 @@ def _kernel(pt_ref, len_ref, layer_ref, win_ref, q_ref, k_ref, v_ref,
 
     @pl.when((base < ln) & (base + page > lo) & (lo < ln))
     def _body():
-        q = q_ref[0]                                  # (H, D)
-        k = k_ref[0, 0]                               # (page, Hkv, D)
-        v = v_ref[0, 0]
-        Hkv = k.shape[1]
-        H = q.shape[0]
-        # GQA: fold query heads onto kv heads: (Hkv, n_rep, D)
-        qg = q.reshape(Hkv, n_rep, -1)
-        s = jnp.einsum("hrd,phd->hrp", qg.astype(jnp.float32),
-                       k.astype(jnp.float32)) * scale
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        s = jnp.where((pos < ln) & (pos >= lo), s, NEG_INF)
-        s = s.reshape(H, page)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        pv = jnp.einsum("hrp,phd->hrd",
-                        p.reshape(Hkv, n_rep, page),
-                        v.astype(jnp.float32))
-        acc_ref[...] = acc_ref[...] * alpha + pv.reshape(H, -1)
-        m_ref[...] = m_new
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, (n_rep, page), 1)
+        valid = (pos < ln) & (pos >= lo)
+        # GQA: kv head h serves query rows [h*n_rep, (h+1)*n_rep).  Each
+        # head is a pair of 2-D dots read straight from the refs — Mosaic
+        # has no layout for folding (H, D) into (Hkv, n_rep, D) in-kernel,
+        # nor for batched dots over the middle (head) axis of a page.
+        # f32 at HIGHEST keeps the products exact on the MXU (default
+        # precision would round the f32 operands to bf16).
+        for h in range(k_ref.shape[3]):
+            rows = pl.ds(h * n_rep, n_rep)
+            q = q_ref[0, rows, :].astype(jnp.float32)          # (n_rep, D)
+            k = k_ref[0, 0, :, h, :].astype(jnp.float32)       # (page, D)
+            v = v_ref[0, 0, :, h, :].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32) * scale
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[rows, :]
+            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[rows, :] = l_ref[rows, :] * alpha \
+                + p.sum(-1, keepdims=True)
+            pv = jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+            acc_ref[rows, :] = acc_ref[rows, :] * alpha + pv
+            m_ref[rows, :] = m_new
 
     @pl.when(j == n_pages - 1)
     def _store():
@@ -111,7 +121,7 @@ def _kernel(pt_ref, len_ref, layer_ref, win_ref, q_ref, k_ref, v_ref,
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
-                    layer=None, window=0, interpret: bool = False,
+                    layer=None, window=0, interpret: bool | None = None,
                     return_state: bool = False):
     """q: (B, H, D); k/v_pages: (P, page, Hkv, D) or, for a layered block
     pool, (L, P, page, Hkv, D) with ``layer`` selecting the plane;
@@ -124,6 +134,7 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
     keys — e.g. the decode step's in-flight token — without renormalizing.
     A lane whose window admits no cached position (length 0, or
     ``window == 1``) comes back as (o=0, m=-inf, l=0) for the merge.
+    ``interpret=None`` lets the platform decide (``pallas_interpret``).
     """
     # concrete-value validation must live outside the jit boundary —
     # inside, every operand is a tracer and isinstance checks are dead
@@ -132,6 +143,8 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
         raise ValueError(
             f"4-D pages have only plane 0, got layer={layer} — a "
             f"calling-convention mix-up (layered pools are 5-D)")
+    if interpret is None:
+        interpret = pallas_interpret()
     return _paged_attention(q, k_pages, v_pages, page_tables, lengths,
                             layer=layer, window=window,
                             interpret=interpret, return_state=return_state)
@@ -202,7 +215,8 @@ def _paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
 
 
 def decode_attend(q, k_new, v_new, k_pages, v_pages, page_tables,
-                  lengths, *, layer=0, window=0, interpret: bool = False):
+                  lengths, *, layer=0, window=0,
+                  interpret: bool | None = None):
     """Decode-step attention: the paged kernel over the cached pages plus
     one online-softmax merge step for the in-flight token (position
     ``lengths[b]``, always attended — it is its own causal context and
@@ -225,7 +239,8 @@ def decode_attend(q, k_new, v_new, k_pages, v_pages, page_tables,
     # score of the in-flight token, same GQA head layout as the kernel
     qg = q.reshape(B, Hkv, n_rep, D)
     s_new = jnp.einsum("bhrd,bhd->bhr", qg.astype(jnp.float32),
-                       k_new.astype(jnp.float32)) * scale
+                       k_new.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST) * scale
     s_new = s_new.reshape(B, H, 1)
     m2 = jnp.maximum(m, s_new)
     alpha = jnp.exp(m - m2)

@@ -75,10 +75,12 @@ write-back (no dirty block is dropped at shutdown), then raises a clear
 opaque NoneType / KeyError; build a new backend to serve again.
 
 Construction goes through ``make_backend`` — the single documented
-entry point (``decode_mode`` / ``kernel_interpret`` / ``tiered`` /
-``shards`` / ``device`` keyword surface).  Passing a pool positionally
-to ``PagedBackend``/``ShardedPagedBackend`` is deprecated; pass
-``pool=``.  Adding a backend: implement the protocol against
+entry point (``decode_mode`` / ``tiered`` / ``shards`` / ``device``
+keyword surface).  Whether the kernel runs compiled or interpreted is
+not an option: ``repro.kernels.pallas_interpret`` decides it from the
+platform (compiled on a TPU, interpreted on the CPU).  Passing a pool
+positionally to ``PagedBackend``/``ShardedPagedBackend`` is deprecated;
+pass ``pool=``.  Adding a backend: implement the protocol against
 ``lm.prefill_parts`` (storage-agnostic prompt run) and
 ``lm.dense_decode_step`` (ragged one-token step), register a
 constructor in ``make_backend``.
@@ -388,10 +390,11 @@ def _paged_decode(params, cfg, tokens, k_pages, v_pages, page_tables,
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
 def _paged_decode_kernel(params, cfg, tokens, k_pages, v_pages,
-                         page_tables, lengths, ssm, conv, interpret=True):
+                         page_tables, lengths, ssm, conv, interpret=None):
     """Kernel-path decode: per-layer Pallas paged attention straight over
     the pool's layered page buffers (no dense gather).  Same operand and
-    result shapes as ``_paged_decode``."""
+    result shapes as ``_paged_decode``.  ``interpret=None`` lets the
+    platform decide (``repro.kernels.pallas_interpret``)."""
     from repro.models import lm
     return lm.paged_decode_step(params, cfg, tokens, k_pages, v_pages,
                                 page_tables, lengths, ssm_state=ssm,
@@ -449,8 +452,7 @@ class PagedBackend:
                  num_blocks: int = 256, block_size: int = 16,
                  placement: str = "mars", eviction: str = "fifo",
                  share_prefixes: bool = True, decode_mode: str = "kernel",
-                 kernel_interpret: bool = True, device=None,
-                 tiered: bool = False, tier_specs=None):
+                 device=None, tiered: bool = False, tier_specs=None):
         """Build a paged backend over ``pool`` (or a fresh pool sized by
         ``num_blocks``/``block_size`` matching the model config).
         Prefer ``make_backend(cfg, "paged", ...)`` — the one documented
@@ -467,13 +469,14 @@ class PagedBackend:
             manager installs its recompute-vs-refetch scoring hook).
           share_prefixes: storage-level prefix sharing via ``PrefixCache``.
           decode_mode: "kernel" (Pallas paged_attention per layer, the
-            default) or "gather" (dense-view oracle).
-          kernel_interpret: run the Pallas kernel in interpret mode
-            (CPU/CI); pass False on real TPU.
-          device: jax device the staged KV mirror and decode operands are
-            committed to; ``None`` uses the default device.  A mesh-
-            sharded deployment (``ShardedPagedBackend``) gives each
-            shard's backend its own device.
+            default) or "gather" (dense-view oracle).  The kernel runs
+            compiled on a TPU and interpreted on the CPU
+            (``kernel_interpret``, resolved from the platform).
+          device: jax device the staged KV mirror, decode operands and
+            this backend's copy of the parameters are committed to;
+            ``None`` uses the default device.  A mesh-sharded deployment
+            (``ShardedPagedBackend``) gives each shard's backend its own
+            device.
           tiered: put host/mock-remote spill tiers behind the pool
             (``kvcache.tiers.TierManager``): eviction demotes registered
             prefix blocks instead of dropping them, and prefix misses
@@ -499,9 +502,13 @@ class PagedBackend:
                 f"frontend prefixes, or has no attention KV at all)")
         if decode_mode not in ("kernel", "gather"):
             raise ValueError(f"unknown decode_mode {decode_mode!r}")
+        from repro.kernels import pallas_interpret
         self.decode_mode = decode_mode
-        self.kernel_interpret = kernel_interpret
+        self.kernel_interpret = pallas_interpret()
         self.device = device
+        # the caller's parameter tree and this backend's copy of it on
+        # ``device`` (see _device_params)
+        self._params_src = self._params_dev = None
         self.cfg = cfg
         if pool is None:
             pool = BlockPool(PoolConfig(
@@ -569,6 +576,19 @@ class PagedBackend:
         on their own mesh device."""
         a = jnp.asarray(x)
         return a if self.device is None else jax.device_put(a, self.device)
+
+    def _device_params(self, params):
+        """``params`` as this backend computes with them: placed on its
+        device once, on first use, and reused for as long as the caller
+        passes the same tree — so no prefill or decode call moves the
+        weights between devices.  Without a device the tree is used as
+        given."""
+        if self.device is None:
+            return params
+        if params is not self._params_src:
+            self._params_src = params
+            self._params_dev = jax.device_put(params, self.device)
+        return self._params_dev
 
     def _staged_pages(self):
         """Stage the pool's host-mutated KV buffers into the next mirror
@@ -678,7 +698,8 @@ class PagedBackend:
                        on_alloc=None) -> tuple[Any, list[int], list[int]]:
         B, S = tokens.shape
         logits, parts = _jit_prefill_parts(
-            params, self.cfg, jnp.asarray(tokens, jnp.int32))
+            self._device_params(params), self.cfg,
+            self._put(np.asarray(tokens, np.int32)))
         kvd = self.cfg.kvdtype
         k_all = np.asarray(parts["k"].astype(kvd))   # (L, B, S, K, dh)
         v_all = np.asarray(parts["v"].astype(kvd))
@@ -976,6 +997,7 @@ class PagedBackend:
                 conv_np[:, i] = s.conv
             ssm = self._put(ssm_np)
             conv = self._put(conv_np)
+        params = self._device_params(params)
         if self.decode_mode == "kernel":
             logits, k_new, v_new, ssm_new, conv_new = _paged_decode_kernel(
                 params, self.cfg, self._put(toks), kp, vp,
@@ -1175,6 +1197,7 @@ class PagedBackend:
         self._mirrors = [None, None]
         self._slot_dirty = [set(), set()]
         self._slot, self._staged_slot = 0, None
+        self._params_src = self._params_dev = None
         self._released = True
 
 
@@ -1219,15 +1242,15 @@ class ShardedPagedBackend:
           n_shards/mesh: shard-count discovery when building the pool —
             forwarded to ``ShardedBlockPool`` (mesh model axis; 1
             without a mesh).
-          devices: per-shard jax devices for the staged mirrors + decode
-            (length ``n_shards``; entries may repeat when fewer devices
-            than shards exist).  None keeps everything on the default
-            device — pool sharding still partitions placement.
+          devices: per-shard jax devices for the staged mirrors, decode
+            operands and each shard's copy of the parameters (length
+            ``n_shards``).  None keeps everything on the default device —
+            pool sharding still partitions placement.
           num_blocks: total capacity request when building a pool; it is
             rounded *up* to a multiple of the shard count, so any
             capacity request is honored.
-          Remaining kwargs (decode_mode, kernel_interpret,
-          share_prefixes, ...) configure every per-shard backend alike.
+          Remaining kwargs (decode_mode, share_prefixes, ...) configure
+          every per-shard backend alike.
         """
         from repro.kvcache.sharded_pool import ShardedBlockPool, \
             discover_shards
@@ -1279,9 +1302,9 @@ class ShardedPagedBackend:
                 "block to its shard pool; build a new backend to serve "
                 "again")
 
-    # decode_mode / kernel staging reads mirror PagedBackend's so the
-    # engine's use_kernel override and the staging tests stay backend-
-    # -agnostic (setter fans out to every shard)
+    # decode_mode / kernel_interpret / kernel staging reads mirror
+    # PagedBackend's so the engine's use_kernel override and the staging
+    # tests stay backend-agnostic (setter fans out to every shard)
 
     @property
     def decode_mode(self) -> str:
@@ -1293,6 +1316,10 @@ class ShardedPagedBackend:
             raise ValueError(f"unknown decode_mode {mode!r}")
         for b in self.backends:
             b.decode_mode = mode
+
+    @property
+    def kernel_interpret(self) -> bool:
+        return self.backends[0].kernel_interpret
 
     @property
     def staged_blocks_last_step(self) -> int:
@@ -1659,9 +1686,9 @@ def make_backend(cfg: ModelConfig, kind: str = "dense", *,
     "dense" | "paged" | "sharded-paged".
 
     One keyword surface configures every kind alike: ``decode_mode``
-    ("kernel"/"gather"), ``kernel_interpret`` (False on real TPU),
-    ``tiered`` (spill tiers behind the pool), ``shards`` (shard count —
-    ``shards > 1`` turns "paged" into the mesh-sharded backend), and
+    ("kernel"/"gather"), ``tiered`` (spill tiers behind the pool),
+    ``shards`` (shard count — ``shards > 1`` turns "paged" into the
+    mesh-sharded backend), and
     ``device`` (the jax device for the staged mirror; per-shard
     ``devices=[...]`` for sharded kinds).
 

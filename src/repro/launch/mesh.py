@@ -11,32 +11,28 @@ from __future__ import annotations
 import os
 
 import jax
-
-
-def auto_axis_types(n: int) -> dict:
-    """``axis_types`` kwarg for ``jax.make_mesh`` where supported.
-    ``jax.sharding.AxisType`` only exists on newer jax; older versions
-    default to Auto semantics anyway, so omit the kwarg there."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n} if at is not None else {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **auto_axis_types(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh() -> jax.sharding.Mesh:
     """1-device mesh with the production axis names (CPU tests)."""
-    return jax.make_mesh((1, 1), ("data", "model"), **auto_axis_types(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def request_cpu_devices(n: int) -> None:
     """Ask XLA for ``n`` host CPU devices (the host-device CPU mesh the
     sharded serve smoke runs on).  Must be called before the first jax
-    device use — backends already initialized ignore the flag, in which
-    case ``make_serve_mesh`` falls back to the devices that exist."""
+    device use — backends already initialized ignore the flag, and
+    ``make_serve_mesh`` then raises for want of devices.  A TPU host
+    ignores it too: there the mesh takes the chips that exist."""
     if n <= 1:
         return
     flags = os.environ.get("XLA_FLAGS", "")
@@ -47,9 +43,14 @@ def request_cpu_devices(n: int) -> None:
 
 def make_serve_mesh(n_shards: int) -> jax.sharding.Mesh:
     """(1, n_shards) serving mesh, axes ("data", "model"): the model axis
-    is what ``ShardedBlockPool`` partitions the KV pool over.  When fewer
-    devices exist than requested (jax already initialized before
-    ``request_cpu_devices``), the mesh shrinks to what is available and
-    pool shards map onto devices round-robin."""
-    n = max(1, min(n_shards, jax.local_device_count()))
-    return jax.make_mesh((1, n), ("data", "model"), **auto_axis_types(2))
+    is what ``ShardedBlockPool`` partitions the KV pool over, one
+    distinct device per shard.  Raises ``ValueError`` when fewer local
+    devices exist than shards — two shards never share a device."""
+    have = jax.local_device_count()
+    if have < n_shards:
+        raise ValueError(
+            f"{n_shards} shards need {n_shards} devices, but only {have} "
+            f"{jax.default_backend()} device(s) exist")
+    return jax.make_mesh((1, n_shards), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=jax.local_devices()[:n_shards])

@@ -11,6 +11,8 @@ per scheduled batch, with and without MARS.
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import time
 
 import jax
@@ -23,10 +25,30 @@ from repro.serve.step import greedy_generate
 from repro.serving.scheduler import MarsScheduler, Request, \
     default_classes, unique_prefix_blocks
 
+# the checkout root: src/repro/launch/serve.py -> <repo>
+_REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
 # --classes N: per-class decode-length profile for the synthetic stream —
 # interactive stays short (chat turns), batch decodes long (summarize),
 # stream sits between; the multipliers scale --new-tokens
 _CLASS_NEW_TOKENS = {"interactive": 1, "batch": 4, "stream": 2}
+
+
+def place_compile_cache() -> str:
+    """Directory JAX keeps its persistent compilation cache in; returns it.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is read by JAX itself and
+    nothing is set here.  Otherwise the cache goes to ``<repo>/.jax_cache``
+    — a fixed path, so a later run from the same checkout finds what this
+    one compiled.  Entry points call it first thing in ``main``; importing
+    a module never does.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(_REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def synth_requests(n: int, vocab: int, n_prefixes: int = 8,
@@ -144,21 +166,20 @@ def main_paged(args):
     from repro.kvcache.backend import make_backend
     from repro.serve.engine import PagedLM, ServeEngine
 
+    t_setup = time.perf_counter()
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get(args.arch)
     assert cfg.n_layers > 1, "full-LM paged serving needs a multi-layer cfg"
     params = lm.init(cfg, jax.random.key(0)).params
     decode_mode = "kernel" if args.kernel_decode else "gather"
+    devices = []
     if args.shards > 1:
         # mesh-sharded serving: one block pool + paged backend per shard
-        # of the serving mesh's model axis, each shard's staged mirror
-        # committed to its own device (round-robin when the host exposes
-        # fewer devices than shards — see request_cpu_devices in main)
+        # of the serving mesh's model axis, each shard's staged mirror,
+        # operands and parameter copy on its own device
         from repro.launch import mesh as mesh_mod
         from repro.sharding import context as shctx
         mesh = mesh_mod.make_serve_mesh(args.shards)
-        mesh_devices = list(mesh.devices.flat)
-        devices = [mesh_devices[s % len(mesh_devices)]
-                   for s in range(args.shards)]
+        devices = list(mesh.devices.flat)
         with shctx.use_mesh(mesh):
             pool_blocks = -(-args.pool_blocks // args.shards) * args.shards
             backend = make_backend(
@@ -166,7 +187,7 @@ def main_paged(args):
                 num_blocks=pool_blocks, block_size=16,
                 decode_mode=decode_mode, tiered=args.tiered_kv)
         print(f"[serve --paged {cfg.name}] shards={args.shards} "
-              f"mesh_devices={len(mesh_devices)} "
+              f"devices={[d.id for d in devices]} "
               f"blocks/shard={backend.pool.shard_blocks}")
     else:
         backend = make_backend(
@@ -192,9 +213,10 @@ def main_paged(args):
                             prefix_len=r.prefix_len,
                             max_new=args.new_tokens * mult,
                             traffic_class=cname))
-    t0 = time.time()
+    t0 = time.perf_counter()
+    setup_s = t0 - t_setup
     finished = eng.run(reqs)
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     pool.check_invariants()
     _dump_metrics(obs, args)
     shard_note = "" if args.shards <= 1 else \
@@ -207,7 +229,8 @@ def main_paged(args):
           f"decode_tokens={eng.stats.decode_tokens} "
           f"prefix_hits={pool.stats.prefix_hits} "
           f"evictions={pool.stats.evictions} "
-          f"pool_rejects={sched.stats.pool_rejects} wall={dt:.1f}s")
+          f"pool_rejects={sched.stats.pool_rejects} "
+          f"setup={setup_s:.1f}s wall={dt:.1f}s")
     if classes:
         for cname, cs in sched.class_stats.items():
             h = sched.wait_hist[cname]
@@ -258,7 +281,11 @@ def main_paged(args):
         f"{backend.decode_mode} paged serving diverged from the dense backend"
     return dict(served=len(finished), steps=eng.stats.steps,
                 prefix_hits=pool.stats.prefix_hits,
-                parity_checked=n_check, decode=backend.decode_mode)
+                parity_checked=n_check, parity_ok=n_check - mismatches,
+                decode=backend.decode_mode,
+                kernel_interpret=backend.kernel_interpret,
+                devices=[d.id for d in devices],
+                setup_s=setup_s, serve_s=dt)
 
 
 def main(argv=None):
@@ -322,14 +349,20 @@ def main(argv=None):
                          "invariant sweep every few engine steps")
     args = ap.parse_args(argv)
 
+    cache_dir = place_compile_cache()
     if args.shards > 1:
         # must precede the first jax device use so the host can present a
         # multi-device CPU mesh (no-op if the backend already initialized;
-        # make_serve_mesh then shrinks to the devices that exist)
+        # make_serve_mesh then raises for want of devices)
         from repro.launch.mesh import request_cpu_devices
         request_cpu_devices(args.shards)
 
     if args.paged:
+        from repro.kernels import pallas_interpret
+        mode = "interpreted" if pallas_interpret() else "compiled"
+        print(f"[serve] platform={jax.default_backend()} "
+              f"devices={jax.device_count()} pallas={mode} "
+              f"compile_cache={cache_dir}")
         return main_paged(args)
 
     cfg = configs.get_smoke(args.arch) if args.smoke \

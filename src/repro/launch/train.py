@@ -21,8 +21,7 @@ from repro import configs
 from repro.data.pipeline import DataConfig, TokenStream
 from repro.ft import checkpoint as ckpt
 from repro.ft.manager import RunSupervisor
-from repro.launch.mesh import (auto_axis_types, make_local_mesh,
-                               make_production_mesh)
+from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.models import lm
 from repro.optim import adamw as optim
 from repro.sharding import context as shctx, rules
@@ -42,7 +41,7 @@ def pick_mesh():
             model = m
             break
     return jax.make_mesh((n // model, model), ("data", "model"),
-                         **auto_axis_types(2))
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def main(argv=None):

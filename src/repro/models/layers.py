@@ -282,7 +282,7 @@ def attention_apply(p, x, cfg: ModelConfig, *, positions, mask,
 
 def paged_attention_apply(p, x, cfg: ModelConfig, *, lengths, k_pages,
                           v_pages, page_tables, layer, window=0,
-                          interpret: bool = True):
+                          interpret: bool | None = None):
     """Decode attention reading cached KV straight from the block pool via
     the Pallas ``paged_attention`` kernel (kernel over the cached pages +
     online-softmax merge of the in-flight token).
@@ -291,8 +291,10 @@ def paged_attention_apply(p, x, cfg: ModelConfig, *, lengths, k_pages,
     buffers; ``layer`` selects the plane — one page table serves every
     layer.  ``window`` > 0 applies the kernel's sliding-window mask (a
     traced int32, so a scan over a ``global_every`` hybrid's layers flips
-    it per layer).  Returns (out (B, 1, d), (k_new, v_new) each
-    (B, 1, K, dh), post-RoPE, for pool write-back after the step).
+    it per layer).  ``interpret=None`` lets the platform decide
+    (``repro.kernels.pallas_interpret``).  Returns (out (B, 1, d),
+    (k_new, v_new) each (B, 1, K, dh), post-RoPE, for pool write-back
+    after the step).
     """
     from repro.kernels.paged_attention.paged_attention import decode_attend
     cd = cfg.cdtype

@@ -432,7 +432,7 @@ def dense_decode_step(params, cfg: ModelConfig, tokens, cache: Cache):
 
 def paged_decode_step(params, cfg: ModelConfig, tokens, k_pages, v_pages,
                       page_tables, lengths, *, ssm_state=None,
-                      conv_state=None, interpret: bool = True):
+                      conv_state=None, interpret: bool | None = None):
     """One-token decode reading cached KV straight from the block pool via
     the Pallas ``paged_attention`` kernel — no gathered dense view.
 
@@ -454,6 +454,8 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, k_pages, v_pages,
     for the caller's pool write-back (write-after-attend: the kernel
     never reads a partially-written page) — and ssm_new/conv_new the
     advanced side state (None for attention-only families).
+    ``interpret=None`` lets the platform decide whether the kernel runs
+    compiled or interpreted (``repro.kernels.pallas_interpret``).
     """
     assert cfg.has_attention and cfg.family not in ("encdec", "vlm"), \
         f"kernel-path decode pages attention KV (+ SSM side state) only " \

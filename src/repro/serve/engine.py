@@ -476,7 +476,7 @@ class ServeEngine:
         if self.use_kernel:
             from repro.kernels.paged_attention.paged_attention import \
                 paged_attention
-            o = paged_attention(q, kp, vp, pt, lengths, interpret=True)
+            o = paged_attention(q, kp, vp, pt, lengths)
         else:
             o = paged_attention_ref(q, kp, vp, pt, lengths)
         return list(self.model.readout(o, [s.salt for s in self.running]))

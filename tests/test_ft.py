@@ -54,8 +54,8 @@ def test_checkpoint_resharding_on_restore(tmp_path):
     """Restore onto a different mesh (elastic restart)."""
     t = {"w": jnp.arange(64, dtype=jnp.float32).reshape(16, 4)}
     ckpt.save(t, 1, tmp_path)
-    from repro.launch.mesh import auto_axis_types
-    mesh = jax.make_mesh((1,), ("data",), **auto_axis_types(1))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
     sh = {"w": jax.NamedSharding(mesh, jax.sharding.PartitionSpec("data"))}
     r = ckpt.restore(jax.eval_shape(lambda: t), 1, tmp_path, shardings=sh)
     np.testing.assert_array_equal(np.asarray(r["w"]), np.asarray(t["w"]))

@@ -62,6 +62,25 @@ def test_flash_attention_noncausal():
 # paged attention
 # ---------------------------------------------------------------------------
 
+def test_pallas_interpret_follows_the_platform(monkeypatch):
+    """Kernels interpret on the CPU, compile on a TPU, and refuse any
+    other platform — decided by one cached helper, never by a default."""
+    from repro.kernels import pallas_interpret
+    assert pallas_interpret() is True        # the tests run on the CPU
+    try:
+        for platform, want in (("tpu", False), ("cpu", True)):
+            pallas_interpret.cache_clear()
+            monkeypatch.setattr(jax, "default_backend", lambda: platform)
+            assert pallas_interpret() is want
+        pallas_interpret.cache_clear()
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            pallas_interpret()
+    finally:
+        monkeypatch.undo()
+        pallas_interpret.cache_clear()
+
+
 @pytest.mark.parametrize("B,H,Hkv,D,page,npages", [
     (2, 4, 2, 64, 16, 4),
     (3, 8, 1, 64, 32, 2),

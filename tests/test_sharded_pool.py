@@ -48,6 +48,39 @@ def test_mesh_discovery_from_model_axis():
     assert ShardedBlockPool(PoolConfig(num_blocks=16)).n_shards == 1
 
 
+def test_serve_mesh_refuses_to_share_devices():
+    """One distinct device per shard: a serving mesh with more shards than
+    devices raises instead of mapping shards onto devices round-robin."""
+    import jax
+    from repro.launch.mesh import make_serve_mesh
+
+    n = jax.local_device_count()
+    mesh = make_serve_mesh(n)
+    assert mesh.shape["model"] == n and len(set(mesh.devices.flat)) == n
+    with pytest.raises(ValueError, match=f"{n + 1} shards need"):
+        make_serve_mesh(n + 1)
+
+
+def test_importing_the_server_leaves_cpu_devices_requestable():
+    """``request_cpu_devices`` works only before JAX starts a backend, so
+    importing the serving stack must not start one — a module-level jnp
+    constant once did, and ``--shards N`` then ran every shard on one CPU
+    device.  Checked in a fresh interpreter (this one has a backend)."""
+    import os
+    import subprocess
+    import sys
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src)
+    env.pop("XLA_FLAGS", None)
+    code = ("from repro.launch import mesh, serve\n"
+            "mesh.request_cpu_devices(3)\n"
+            "import jax\n"
+            "assert jax.local_device_count() == 3, jax.devices()\n")
+    subprocess.run([sys.executable, "-c", code], env=env, check=True,
+                   timeout=300)
+
+
 def test_placement_key_leads_with_shard():
     # the device/shard coordinate orders ahead of the bank+row-group key:
     # a later row group on an earlier shard sorts first
@@ -216,6 +249,29 @@ def test_sharded_matches_single_pool_backend():
     assert [p.num_live for p in sharded.pool.shards] == [3, 3]
     single.release()
     sharded.release()
+
+
+def test_shard_backends_hold_params_on_their_device():
+    """Each shard's backend places the parameters on its device once, on
+    first use, and reuses that copy — no later prefill or decode moves
+    the weights again; release drops the copy."""
+    import jax
+    from repro.kvcache.backend import ShardedPagedBackend
+
+    cfg, params = _model()
+    dev = jax.devices()[0]
+    b = ShardedPagedBackend(cfg, n_shards=2, num_blocks=32, block_size=4,
+                            decode_mode="gather", devices=[dev, dev])
+    sids = [b.new_seq(params, [1, 2, 3, 4, 5], shard=s)[0] for s in (0, 1)]
+    held = [inner._params_dev for inner in b.backends]
+    for _ in range(3):
+        b.decode(params, sids, [7, 7])
+    for inner, h in zip(b.backends, held):
+        assert inner._params_dev is h
+        assert all(x.committed and x.devices() == {dev}
+                   for x in jax.tree.leaves(h))
+    b.release()
+    assert all(inner._params_dev is None for inner in b.backends)
 
 
 # ---------------------------------------------------------------------------

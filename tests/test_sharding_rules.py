@@ -11,8 +11,8 @@ from repro.sharding import rules
 @pytest.fixture(scope="module")
 def mesh():
     n = len(jax.devices())
-    from repro.launch.mesh import auto_axis_types
-    return jax.make_mesh((1, 1), ("data", "model"), **auto_axis_types(2))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def _spec(axes, shape, mesh, fsdp=True):

@@ -1,0 +1,97 @@
+"""Compile-only checks that the served Pallas kernels lower for TPU v5e.
+
+The TPU compiler installed with JAX compiles for a described (not
+attached) ``v5e:2x2`` topology from a CPU host, so these tests catch what
+interpret-mode tests never see — a kernel Mosaic refuses — at real
+widths and no chip time.  Nothing runs: each test compiles for one chip
+of the topology and asserts the program holds the kernel
+(``tpu_custom_call``).  ``interpret=False`` is passed explicitly, since
+``jax.default_backend()`` is still the CPU here.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro import configs
+from repro.kernels.paged_attention.paged_attention import decode_attend
+from repro.kvcache.backend import _paged_decode_kernel
+from repro.models import lm
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")    # no compiler logs on disk
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:     # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A compile for a described chip can be written to the persistent
+    cache but not read back without the chip; keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("name,window", [("qwen1_5_0_5b", 0),
+                                         ("hymba_1_5b", 1024)])
+def test_paged_attention_compiles_for_v5e(one_chip, no_compile_cache,
+                                          name, window):
+    """Decode attention (kernel over the cached pages + in-flight merge)
+    at the published head layout: qwen's 16/16 heads, and hymba's GQA
+    25/5 heads under its 1024-token sliding window."""
+    cfg = configs.get(name)
+    B, page, P, n_pages = 8, 16, 256, 8
+    H, K, D = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
+    cd, kvd = cfg.cdtype, cfg.kvdtype
+
+    def step(q, k_new, v_new, kp, vp, pt, lengths, layer):
+        return decode_attend(q, k_new, v_new, kp, vp, pt, lengths,
+                             layer=layer, window=window, interpret=False)
+
+    pool = _spec((cfg.n_layers, P, page, K, D), kvd, one_chip)
+    compiled = jax.jit(step).lower(
+        _spec((B, H, D), cd, one_chip), _spec((B, K, D), cd, one_chip),
+        _spec((B, K, D), cd, one_chip), pool, pool,
+        _spec((B, n_pages), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip),
+        _spec((), jnp.int32, one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_decode_step_compiles_for_v5e(one_chip, no_compile_cache):
+    """The whole jitted kernel-path decode step at qwen1.5-0.5B's full
+    width (24 layers, vocab 151,936), parameters as shapes only."""
+    cfg = configs.get("qwen1_5_0_5b")
+    B, n_pages, P = 8, 4, 256
+    params = jax.tree.map(
+        lambda a: _spec(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda: lm.init(cfg, jax.random.key(0)).params))
+    pool = _spec((cfg.n_layers, P, 16, cfg.n_kv_heads, cfg.d_head),
+                 cfg.kvdtype, one_chip)
+    compiled = _paged_decode_kernel.lower(
+        params, cfg, _spec((B, 1), jnp.int32, one_chip), pool, pool,
+        _spec((B, n_pages), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip), None, None,
+        interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
