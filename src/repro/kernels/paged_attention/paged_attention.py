@@ -210,6 +210,7 @@ def _paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
                    jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
                    jax.ShapeDtypeStruct((B, H, 1), jnp.float32)],
         interpret=interpret,
+        name="paged_attention",
     )(page_tables, lengths, layer_arr, win_arr, q, k_pages, v_pages)
     return (o, m, l) if return_state else o
 

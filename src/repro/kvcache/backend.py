@@ -100,6 +100,8 @@ import numpy as np
 from repro.kvcache.pool import BlockPool, PoolConfig
 from repro.kvcache.prefix import BlockTable, PrefixCache
 from repro.models.config import ModelConfig
+from repro.obs.metrics import StatGroup
+from repro.obs.trace import span
 
 
 @dataclasses.dataclass
@@ -420,6 +422,16 @@ def _pow2(n: int) -> int:
     return 1 << max(n - 1, 0).bit_length()
 
 
+class BackendStats(StatGroup):
+    """What a ``PagedBackend`` moves between host and device: bytes up
+    (every upload goes through ``_put``), bytes down (every read-back
+    through ``_fetch``), pool blocks staged into the device mirrors, and
+    decode steps dispatched.  Plain counters, always on; adopted as
+    ``backend.<field>`` by ``Observer.attach``."""
+    FIELDS = {"h2d_bytes": 0, "d2h_bytes": 0, "staged_blocks": 0,
+              "decode_steps": 0}
+
+
 @dataclasses.dataclass
 class _PagedSeq:
     sid: int
@@ -541,10 +553,12 @@ class PagedBackend:
         self._next_sid = 0
         self._batch: list[int] = []      # batch-level API lane order
         self._released = False
-        # telemetry (obs.Observer.attach): spans + the live row-locality
-        # feed; obs_shard tags events with this backend's shard index
+        # telemetry (obs.Observer.attach): JSONL spans + the live
+        # row-locality feed; obs_shard tags events with this backend's
+        # shard index.  The byte counters are always on.
         self.obs = None
         self.obs_shard = 0
+        self.stats = BackendStats()
         # double-buffered device mirrors of the pool's KV buffers: two
         # (k, v) slots, swapped every stage, each with its own pending-
         # dirty set (both fed from pool.drain_dirty — this backend is the
@@ -570,12 +584,25 @@ class PagedBackend:
 
     # -- device staging ------------------------------------------------------
 
-    def _put(self, x):
-        """Commit an operand to this backend's device (default device when
-        unset) — per-shard backends keep their mirrors and decode inputs
-        on their own mesh device."""
+    @property
+    def _log(self):
+        """The attached observer's JSONL trace, or None."""
+        return None if self.obs is None else self.obs.trace
+
+    def _put(self, x: np.ndarray):
+        """Upload a host array to this backend's device (default device
+        when unset) — per-shard backends keep their mirrors and decode
+        inputs on their own mesh device.  Counts ``h2d_bytes``."""
+        self.stats.h2d_bytes += x.nbytes
         a = jnp.asarray(x)
         return a if self.device is None else jax.device_put(a, self.device)
+
+    def _fetch(self, x) -> np.ndarray:
+        """Read a device array back to the host (blocking until it is
+        computed).  Counts ``d2h_bytes``."""
+        a = np.asarray(x)
+        self.stats.d2h_bytes += a.nbytes
+        return a
 
     def _device_params(self, params):
         """``params`` as this backend computes with them: placed on its
@@ -627,6 +654,7 @@ class PagedBackend:
                     _scatter_blocks(v, idx, self._put(pool.v_pages[:, pad])))
             self._slot_dirty[s].clear()
             self._staged_slot, self._slot = s, 1 - s
+        self.stats.staged_blocks += self.staged_blocks_last_step
         if self.obs is not None:
             self.obs.trace.event("backend.stage", shard=self.obs_shard,
                                  blocks=self.staged_blocks_last_step,
@@ -685,28 +713,40 @@ class PagedBackend:
         # (this is also what keeps a dispatched step's capacity precheck
         # valid until its own commit)
         self.flush()
-        if self.obs is not None:
-            with self.obs.trace.span("backend.prefill",
-                                     shard=self.obs_shard,
-                                     rows=int(tokens.shape[0])) as sp:
-                out = self._add_seqs_impl(params, tokens, on_alloc)
-                sp["shared_tokens"] = int(sum(out[2]))
-                return out
-        return self._add_seqs_impl(params, tokens, on_alloc)
-
-    def _add_seqs_impl(self, params, tokens: np.ndarray,
-                       on_alloc=None) -> tuple[Any, list[int], list[int]]:
         B, S = tokens.shape
-        logits, parts = _jit_prefill_parts(
-            self._device_params(params), self.cfg,
-            self._put(np.asarray(tokens, np.int32)))
-        kvd = self.cfg.kvdtype
-        k_all = np.asarray(parts["k"].astype(kvd))   # (L, B, S, K, dh)
-        v_all = np.asarray(parts["v"].astype(kvd))
-        ssm_all = conv_all = None
-        if self.cfg.has_ssm:
-            ssm_all = np.asarray(parts["ssm"], np.float32)
-            conv_all = np.asarray(parts["conv"])
+        with span("backend.prefill", self._log, shard=self.obs_shard,
+                  rows=B) as sp:
+            logits, parts = _jit_prefill_parts(
+                self._device_params(params), self.cfg,
+                self._put(np.asarray(tokens, np.int32)))
+            kvd = self.cfg.kvdtype
+            k_dev, v_dev = parts["k"].astype(kvd), parts["v"].astype(kvd)
+            state = (parts["ssm"], parts["conv"]) if self.cfg.has_ssm \
+                else None
+            with span("backend.prefill.wait", rows=B, tokens=S):
+                jax.block_until_ready((logits, k_dev, v_dev, state))
+            with span("backend.prefill.fetch", rows=B, tokens=S):
+                logits = self._fetch(logits)
+                k_all = self._fetch(k_dev)            # (L, B, S, K, dh)
+                v_all = self._fetch(v_dev)
+                ssm_all = conv_all = None
+                if state is not None:
+                    ssm_all = np.asarray(self._fetch(state[0]), np.float32)
+                    conv_all = self._fetch(state[1])
+            with span("backend.prefill.store", rows=B, tokens=S):
+                sids, shared = self._store_prompts(
+                    tokens, k_all, v_all, ssm_all, conv_all, on_alloc)
+            sp["shared_tokens"] = int(sum(shared))
+        return np.asarray(logits[:, 0], np.float32), sids, shared
+
+    def _store_prompts(self, tokens, k_all, v_all, ssm_all, conv_all,
+                       on_alloc) -> tuple[list[int], list[int]]:
+        """The host half of a prefill: match each row's prompt against
+        the prefix cache, extend a fresh block table with the rest of
+        its K/V (written into the numpy pool), register the sequence.
+        Returns ``(sids, shared prefix tokens)``, rolled back whole on
+        pool exhaustion (see ``_add_seqs``)."""
+        B = tokens.shape[0]
         sids, shared = [], []
         for b in range(B):
             prompt = [int(t) for t in tokens[b]]
@@ -755,7 +795,7 @@ class PagedBackend:
             # copy-in; the dirtied blocks re-stage to the device mirror
             # before the next decode step touches them
             self.tiers.flush_promotions()
-        return np.asarray(logits[:, 0], np.float32), sids, shared
+        return sids, shared
 
     def fork_seq(self, sid: int) -> int:
         """Fork a sequence, sharing every block (CoW on first append);
@@ -841,7 +881,7 @@ class PagedBackend:
             bids, n = self.tiers.match(tokens)
         else:
             bids, n = self.prefix.match(tokens, pool)
-        # as in ``_add_seqs_impl``: the on_alloc claim counts only the
+        # as in ``_store_prompts``: the on_alloc claim counts only the
         # restore's own allocations (tier promotion destinations are the
         # tier manager's business, not the caller's reservation)
         allocs0 = pool.stats.allocs
@@ -930,7 +970,6 @@ class PagedBackend:
         Raising ("pool exhausted", or a second dispatch while one step
         is in flight) leaves every sequence exactly as it was.
         """
-        from repro.kernels.paged_attention import ops
         self._check_released()
         batch_api = sids is None
         if batch_api:
@@ -941,6 +980,15 @@ class PagedBackend:
             raise RuntimeError(
                 "a decode step is already in flight; sync() it before "
                 "dispatching the next")
+        with span("backend.dispatch", step=self._steps, lanes=len(sids)):
+            return self._dispatch(params, sids, tokens, batch_api,
+                                  on_alloc)
+
+    def _dispatch(self, params, sids, tokens, batch_api: bool,
+                  on_alloc) -> DecodeStep:
+        """The body of ``dispatch_decode`` once its arguments check out:
+        commit, precheck, stage, launch."""
+        from repro.kernels.paged_attention import ops
         self._commit_pending()
         # tier contract: every queued promotion flushed (copy-in complete,
         # block dirtied for staging) before a promoted page can enter a
@@ -971,7 +1019,8 @@ class PagedBackend:
         # out of registers, but shares the padding so both compile alike)
         pt, lengths, toks = ops.decode_step_operands(
             [s.table for s in seqs], tokens, page)
-        kp, vp = self._staged_pages()
+        with span("backend.stage", step=self._steps, lanes=B):
+            kp, vp = self._staged_pages()
         if self.obs is not None:
             # live row-locality: this step's page walk in kernel issue
             # order (sequence-major, page-contiguous — the MARS-reordered
@@ -984,29 +1033,20 @@ class PagedBackend:
                                          block_size=page))
         ssm = conv = None
         if self.cfg.has_ssm:
-            # batch the per-sequence hybrid side state (padded lanes get
-            # zeros; their outputs are discarded at sync)
-            L = self.cfg.n_layers
-            Bp = toks.shape[0]
-            ssm_np = np.zeros((L, Bp) + seqs[0].ssm.shape[1:],
-                              seqs[0].ssm.dtype)
-            conv_np = np.zeros((L, Bp) + seqs[0].conv.shape[1:],
-                               seqs[0].conv.dtype)
-            for i, s in enumerate(seqs):
-                ssm_np[:, i] = s.ssm
-                conv_np[:, i] = s.conv
-            ssm = self._put(ssm_np)
-            conv = self._put(conv_np)
-        params = self._device_params(params)
-        if self.decode_mode == "kernel":
-            logits, k_new, v_new, ssm_new, conv_new = _paged_decode_kernel(
-                params, self.cfg, self._put(toks), kp, vp,
-                self._put(pt), self._put(lengths), ssm, conv,
-                interpret=self.kernel_interpret)
-        else:
-            logits, k_new, v_new, ssm_new, conv_new = _paged_decode(
-                params, self.cfg, self._put(toks), kp, vp,
-                self._put(pt), self._put(lengths), ssm, conv)
+            with span("backend.state_pack", step=self._steps, lanes=B):
+                ssm, conv = self._pack_state(seqs, toks.shape[0])
+        with span("backend.launch", step=self._steps, lanes=B):
+            params = self._device_params(params)
+            if self.decode_mode == "kernel":
+                logits, k_new, v_new, ssm_new, conv_new = \
+                    _paged_decode_kernel(
+                        params, self.cfg, self._put(toks), kp, vp,
+                        self._put(pt), self._put(lengths), ssm, conv,
+                        interpret=self.kernel_interpret)
+            else:
+                logits, k_new, v_new, ssm_new, conv_new = _paged_decode(
+                    params, self.cfg, self._put(toks), kp, vp,
+                    self._put(pt), self._put(lengths), ssm, conv)
         step = DecodeStep(index=self._steps, sids=list(sids),
                           tokens=[int(t) for t in tokens],
                           staged=self.staged_blocks_last_step,
@@ -1015,12 +1055,27 @@ class PagedBackend:
         step.dev.update(logits=logits, k=k_new, v=v_new,
                         ssm=ssm_new, conv=conv_new)
         self._steps += 1
+        self.stats.decode_steps += 1
         self._inflight = step
         if self.obs is not None:
             self.obs.trace.event("backend.dispatch", shard=self.obs_shard,
                                  step=step.index, lanes=B,
                                  staged=step.staged)
         return step
+
+    def _pack_state(self, seqs, Bp: int):
+        """Batch the per-sequence hybrid side state for one step and
+        upload it (padded lanes get zeros; their outputs are discarded
+        at sync)."""
+        L = self.cfg.n_layers
+        ssm_np = np.zeros((L, Bp) + seqs[0].ssm.shape[1:],
+                          seqs[0].ssm.dtype)
+        conv_np = np.zeros((L, Bp) + seqs[0].conv.shape[1:],
+                           seqs[0].conv.dtype)
+        for i, s in enumerate(seqs):
+            ssm_np[:, i] = s.ssm
+            conv_np[:, i] = s.conv
+        return self._put(ssm_np), self._put(conv_np)
 
     def sync(self, step: DecodeStep):
         """Block on a dispatched step's logits.  The new K/V stays on
@@ -1035,24 +1090,24 @@ class PagedBackend:
             raise RuntimeError(
                 "sync() of a step that is not in flight on this backend")
         B = len(step.sids)
-        if self.obs is not None:
-            # the span measures the blocking wait — dispatch-to-sync gap
-            with self.obs.trace.span("backend.decode",
-                                     shard=self.obs_shard,
-                                     step=step.index, lanes=B) as sp:
-                sp["staged"] = step.staged
-                logits = np.asarray(step.dev.pop("logits"))
-        else:
-            logits = np.asarray(step.dev.pop("logits"))
+        # the span measures the blocking wait (the dispatch-to-sync gap)
+        # and the logits coming to the host
+        with span("backend.decode", self._log, shard=self.obs_shard,
+                  step=step.index, lanes=B, staged=step.staged):
+            logits = step.dev.pop("logits")
+            with span("backend.decode.wait", step=step.index, lanes=B):
+                logits.block_until_ready()
+            with span("backend.decode.fetch", step=step.index, lanes=B):
+                step.logits = np.asarray(self._fetch(logits)[:B, 0],
+                                         np.float32)
         # logits landing means the step finished; start the KV transfer
         # for the deferred commit without blocking on it
         for name in ("k", "v", "ssm", "conv"):
             arr = step.dev.get(name)
             if arr is not None and hasattr(arr, "copy_to_host_async"):
                 arr.copy_to_host_async()
-        step.logits = np.asarray(logits[:B, 0], np.float32)
         if step.batch_api:
-            step.logits = jnp.asarray(step.logits)[:, None, :]
+            step.logits = self._put(step.logits)[:, None, :]
         step.synced = True
         self._inflight = None
         self._pending = step
@@ -1083,13 +1138,29 @@ class PagedBackend:
         if step is None:
             return
         self._pending = None
-        k_new = np.asarray(step.dev.pop("k"))   # (L, Bp, 1, K, dh)
-        v_new = np.asarray(step.dev.pop("v"))
-        ssm_new = step.dev.pop("ssm")
-        conv_new = step.dev.pop("conv")
-        if ssm_new is not None:
-            ssm_new = np.asarray(ssm_new)       # (L, Bp, H, P, N)
-            conv_new = np.asarray(conv_new)
+        B = len(step.sids)
+        with span("backend.commit", step=step.index, lanes=B):
+            with span("backend.commit.fetch", step=step.index, lanes=B):
+                k_new = self._fetch(step.dev.pop("k"))   # (L, Bp, 1, K, dh)
+                v_new = self._fetch(step.dev.pop("v"))
+                ssm_new = step.dev.pop("ssm")
+                conv_new = step.dev.pop("conv")
+                if ssm_new is not None:
+                    ssm_new = self._fetch(ssm_new)       # (L, Bp, H, P, N)
+                    conv_new = self._fetch(conv_new)
+            with span("backend.commit.store", step=step.index, lanes=B):
+                self._store_step(step, k_new, v_new, ssm_new, conv_new)
+        step.committed = True
+        step.seqs = None
+        if self.obs is not None:
+            self.obs.trace.event("backend.commit", shard=self.obs_shard,
+                                 step=step.index, lanes=len(step.sids))
+
+    def _store_step(self, step: DecodeStep, k_new, v_new, ssm_new,
+                    conv_new) -> None:
+        """Append each lane's new token and K/V to its block table (CoW
+        on shared tails), carry the hybrid side state, fire
+        ``on_alloc``."""
         for i, (s, tok) in enumerate(zip(step.seqs, step.tokens)):
             allocs0 = self.pool.stats.allocs
             new_tokens = s.tokens + [int(tok)]
@@ -1103,11 +1174,6 @@ class PagedBackend:
                 s.conv = np.ascontiguousarray(conv_new[:, i])
             if step.on_alloc is not None:
                 step.on_alloc(s.sid, self.pool.stats.allocs - allocs0)
-        step.committed = True
-        step.seqs = None
-        if self.obs is not None:
-            self.obs.trace.event("backend.commit", shard=self.obs_shard,
-                                 step=step.index, lanes=len(step.sids))
 
     def flush(self) -> None:
         """Barrier: sync any in-flight step and commit any pending
@@ -1116,9 +1182,10 @@ class PagedBackend:
         a released backend never holds pending work; flushing after
         release raises like every other entry point."""
         self._check_released()
-        if self._inflight is not None:
-            self.sync(self._inflight)
-        self._commit_pending()
+        with span("backend.flush"):
+            if self._inflight is not None:
+                self.sync(self._inflight)
+            self._commit_pending()
 
     @property
     def inflight_steps(self) -> int:
@@ -1162,7 +1229,7 @@ class PagedBackend:
         for sid in old:              # re-prefill replaces the batch lanes
             self.free_seq(sid)
         logits, self._batch, _ = self._add_seqs(params, np.asarray(tokens))
-        return jnp.asarray(logits)[:, None, :]
+        return self._put(logits)[:, None, :]
 
     def decode_step(self, params, tokens):
         """Protocol ``decode_step``: advance the prefill lanes one token
@@ -1171,7 +1238,7 @@ class PagedBackend:
         self._check_released()
         toks = [int(t) for t in np.asarray(tokens).reshape(-1)]
         logits = self.decode(params, self._batch, toks)
-        return jnp.asarray(logits)[:, None, :]
+        return self._put(logits)[:, None, :]
 
     @property
     def lengths(self) -> np.ndarray:
@@ -1324,6 +1391,14 @@ class ShardedPagedBackend:
     @property
     def staged_blocks_last_step(self) -> int:
         return sum(b.staged_blocks_last_step for b in self.backends)
+
+    @property
+    def stats(self) -> BackendStats:
+        """The shards' ``BackendStats`` summed (a snapshot; each shard's
+        own counters are adopted as ``backend.shardN.<field>``)."""
+        return BackendStats(**{f: sum(getattr(b.stats, f)
+                                      for b in self.backends)
+                               for f in BackendStats.FIELDS})
 
     # -- sequence-level API (what the serve engine drives) ------------------
 

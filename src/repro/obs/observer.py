@@ -10,6 +10,8 @@ their stats facades into the registry:
     sched.<field>         SchedulerStats     (scheduled, shard_defers, ...)
     pool.<field>          aggregate PoolStats
     pool.shardN.<field>   per-shard PoolStats (sharded pools)
+    backend.<field>       BackendStats       (h2d_bytes, d2h_bytes, ...)
+    backend.shardN.<field>  per-shard BackendStats (sharded backends)
 
 Instrumented code pays ONE attribute test (``if self.obs is not None``)
 when telemetry is off — nothing else; see ``docs/OBSERVABILITY.md`` for
@@ -125,10 +127,13 @@ class Observer:
             self.registry.adopt("pool", pool.stats)
         backend = getattr(engine.model, "backend", None)
         if backend is not None:
-            inners = getattr(backend, "backends", None) or [backend]
+            sharded = getattr(backend, "backends", None)
+            inners = sharded or [backend]
             for i, b in enumerate(inners):
                 b.obs = self
                 b.obs_shard = i
+                self.registry.adopt(
+                    f"backend.shard{i}" if sharded else "backend", b.stats)
                 tiers = getattr(b, "tiers", None)
                 if tiers is not None:
                     tiers.obs = self

@@ -32,6 +32,12 @@ Design points:
 ...     t.event("engine.token", rid=1)
 >>> [e["ev"] for e in t.events()]     # ordered by entry timestamp
 ['engine.step', 'engine.token']
+
+``span(name, log, **fields)`` is the one instrumentation helper the
+serving stack calls: it always enters a ``jax.profiler.TraceAnnotation``
+(a host event on the profiler's clock, the fields as its stats, when a
+profiler trace is being taken; about a microsecond when none is), and
+with a ``TraceLog`` it also records the JSONL span above.
 """
 from __future__ import annotations
 
@@ -40,6 +46,8 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from typing import Callable, Iterator, Optional
+
+from jax import profiler
 
 
 class TraceLog:
@@ -103,3 +111,37 @@ class TraceLog:
                 fh.write(line + "\n")
         self._buf.clear()
         return len(evs)
+
+
+class span:
+    """A timed region on the profiler's clock, and in ``log`` too.
+
+    Always enters ``jax.profiler.TraceAnnotation(name, **fields)``: while
+    a profiler trace is being taken the region lands in its host plane,
+    on the same clock as the device's operations, with ``fields`` as the
+    event's stats; otherwise it costs about a microsecond.  Given a
+    ``TraceLog`` it also records the JSONL span (``TraceLog.span``).
+    Yields the span's field dict: with a log, the JSONL event's (fields
+    added inside the region land in the JSONL line only), else a
+    scratch dict.
+    """
+    __slots__ = ("_ann", "_log", "_fields")
+
+    def __init__(self, name: str, log: Optional[TraceLog] = None,
+                 **fields):
+        self._ann = profiler.TraceAnnotation(name, **fields)
+        self._log = None if log is None else log.span(name, **fields)
+        self._fields = fields
+
+    def __enter__(self) -> dict:
+        self._ann.__enter__()
+        if self._log is None:
+            return self._fields
+        return self._log.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        try:
+            if self._log is not None:
+                self._log.__exit__(*exc)
+        finally:
+            self._ann.__exit__(*exc)

@@ -38,6 +38,7 @@ import dataclasses
 import time
 from typing import Optional, Union
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -46,6 +47,7 @@ from repro.kernels.paged_attention.ref import paged_attention_ref
 from repro.kvcache.pool import BlockPool
 from repro.kvcache.prefix import BlockTable, PrefixCache
 from repro.obs.metrics import StatGroup
+from repro.obs.trace import span
 from repro.serving.scheduler import MarsScheduler, Request
 
 
@@ -230,15 +232,14 @@ class ServeEngine:
 
     def _prefill(self, req: Request) -> list[SeqState]:
         prompt = list(req.prompt)
-        if self.obs is not None:
-            shared0 = self.stats.shared_prompt_tokens
-            with self.obs.trace.span("engine.prefill", rid=req.rid,
-                                     tokens=len(prompt)) as sp:
-                seqs = self._prefill_impl(req, prompt)
-                sp["lanes"] = len(seqs)
-                sp["shared"] = self.stats.shared_prompt_tokens - shared0
-                return seqs
-        return self._prefill_impl(req, prompt)
+        shared0 = self.stats.shared_prompt_tokens
+        log = None if self.obs is None else self.obs.trace
+        with span("engine.prefill", log, rid=req.rid,
+                  tokens=len(prompt)) as sp:
+            seqs = self._prefill_impl(req, prompt)
+            sp["lanes"] = len(seqs)
+            sp["shared"] = self.stats.shared_prompt_tokens - shared0
+        return seqs
 
     def _prefill_impl(self, req: Request, prompt: list) -> list[SeqState]:
         self._claims[req.rid] = self._claims.get(req.rid, 0) \
@@ -303,6 +304,16 @@ class ServeEngine:
         if not self.running and not self.paused \
                 and not len(self.scheduler):
             return 0
+        # the backend's byte counters at entry put them on the trace's
+        # clock: a reader differences them between two steps
+        totals = {}
+        if self._lm is not None:
+            totals = self._lm.backend.stats.as_dict()
+        with jax.profiler.StepTraceAnnotation(
+                "engine.step", step_num=self.stats.steps, **totals):
+            return self._step(now)
+
+    def _step(self, now: float) -> int:
         obs = self.obs
         t0 = time.perf_counter() if obs is not None else 0.0
         # overload first: a latency-class arrival bounced since the last
@@ -312,8 +323,10 @@ class ServeEngine:
         free = self.max_lanes - len(self.running)
         if free > 0:
             # a request occupies one decode lane per forked sample
-            for req in self.scheduler.schedule_batch(
-                    free, now=now, cost_fn=lambda r: r.n_samples):
+            with span("engine.schedule", lanes=free):
+                admitted = self.scheduler.schedule_batch(
+                    free, now=now, cost_fn=lambda r: r.n_samples)
+            for req in admitted:
                 if obs is not None:
                     obs.trace.event("engine.admit", rid=req.rid,
                                     n_samples=req.n_samples)
@@ -329,9 +342,10 @@ class ServeEngine:
         if self._sharded and self._lm is not None:
             shard_ids = [self._lm.backend.shard_of(s.sid)
                          for s in self.running]
-        order = ops.batch_lane_order(
-            [s.table for s in self.running],
-            self.pool.cfg.blocks_per_group, shard_ids=shard_ids)
+        with span("engine.lane_order", lanes=len(self.running)):
+            order = ops.batch_lane_order(
+                [s.table for s in self.running],
+                self.pool.cfg.blocks_per_group, shard_ids=shard_ids)
         self.running = [self.running[i] for i in order]
 
         nxt = self._decode_lm() if self._lm is not None \
@@ -498,8 +512,9 @@ class ServeEngine:
                 logits = lm.backend.decode(
                     lm.params, [s.sid for s in live],
                     [s.tokens[-1] for s in live], on_alloc=self._on_alloc)
-            for s, lg in zip(live, logits):
-                nxt[id(s)] = lm.next_token(lg, s.salt)
+            with span("engine.sample", lanes=len(live)):
+                for s, lg in zip(live, logits):
+                    nxt[id(s)] = lm.next_token(lg, s.salt)
         return [nxt[id(s)] for s in self.running]
 
     def _decode_lm_pipelined(self, live: list) -> list:

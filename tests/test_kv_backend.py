@@ -412,3 +412,56 @@ def test_paged_prefix_sharing_shares_storage():
     np.testing.assert_allclose(l1, l2, rtol=1e-5, atol=1e-5)
     backend.release()
     assert backend.pool.num_live == 0
+
+
+# ---------------------------------------------------------------------------
+# host<->device byte counters
+# ---------------------------------------------------------------------------
+
+def _pow2(n):
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def test_backend_counts_the_bytes_it_moves():
+    """After one prefill and three decode steps the counters hold what
+    the shapes say crossed.  Up: the prompt's tokens; both mirror slots'
+    K and V whole at the first stage, then the dirty blocks padded to a
+    power of two with their ids; each step's tokens, page table and
+    lengths (lanes and pages padded to powers of two).  Down: the
+    prompt's last logits and K/V; each step's logits and new K/V."""
+    from repro.obs import Observer
+    from repro.serve.engine import PagedLM, ServeEngine
+    from repro.serving.scheduler import MarsScheduler
+    cfg, params = _model("qwen1_5_0_5b")
+    N, bs, S = 32, 4, 6
+    be = PagedBackend(cfg, num_blocks=N, block_size=bs,
+                      decode_mode="gather")
+    L, K, dh, V = cfg.n_layers, cfg.n_kv_heads, cfg.d_head, cfg.vocab
+    kv = jnp.dtype(cfg.kvdtype).itemsize
+    lg = jnp.dtype(cfg.cdtype).itemsize
+    i32 = 4
+    plane = L * bs * K * dh * kv          # one block's K (or V), all layers
+    sid, logits, _ = be.new_seq(params, list(range(1, S + 1)))
+    h2d, d2h = S * i32, V * lg + 2 * L * S * K * dh * kv
+    staged = []
+    for i in range(3):
+        tok = int(np.argmax(logits if logits.ndim == 1 else logits[0]))
+        logits = be.decode(params, [sid], [tok])
+        staged.append(be.staged_blocks_last_step)
+        n_pages = _pow2(-(-(S + i + 1) // bs))
+        h2d += i32 + n_pages * i32 + i32      # one lane: Bp = 1
+        d2h += V * lg + 2 * L * K * dh * kv
+    assert staged[0] == N
+    h2d += 2 * 2 * N * plane
+    h2d += sum(_pow2(n) * (i32 + 2 * plane) for n in staged[1:] if n)
+    st = be.stats
+    assert (st.h2d_bytes, st.d2h_bytes) == (h2d, d2h)
+    assert st.staged_blocks == sum(staged)
+    assert st.decode_steps == 3
+
+    eng = ServeEngine(be.pool, MarsScheduler(pool=be.pool),
+                      PagedLM(params, cfg, be))
+    snap = Observer().attach(eng).snapshot()["counters"]
+    assert snap["backend.h2d_bytes"] == h2d
+    assert snap["backend.d2h_bytes"] == d2h
+    assert snap["backend.staged_blocks"] == sum(staged)

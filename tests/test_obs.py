@@ -1,8 +1,10 @@
 """Observability layer: registry/facade semantics, trace spans, the
 incremental open-row model vs the DRAM reference, shard load snapshots,
 the O(dirty) incremental pool sweep, and the Observer end-to-end."""
+import glob
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -14,6 +16,7 @@ from repro.kvcache.sharded_pool import ShardedBlockPool
 from repro.obs import (Counter, Histogram, MetricsRegistry, Observer,
                        OpenRowCounter, StatGroup, TraceLog,
                        shard_load_snapshot)
+from repro.obs.trace import span
 from repro.serve.engine import ServeEngine
 from repro.serving.scheduler import MarsScheduler, Request
 
@@ -129,6 +132,108 @@ def test_trace_spans_nest_and_time_deterministically():
     # fake clock ticks 10us per read: spans carry entry ts + duration
     assert outer["ts"] < point["ts"] < inner["ts"]
     assert outer["dur_us"] > inner["dur_us"] > 0
+
+
+def _host_events(tmp_path, fn) -> list:
+    """``(name, start_ns, end_ns, stats)`` of every program span (names
+    ``engine.*`` and ``backend.*``) a CPU profiler trace of ``fn()``
+    holds."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith(("engine.", "backend."))]
+
+
+def test_span_lands_in_the_profiler_with_its_fields(tmp_path):
+    def run():
+        with span("backend.dispatch", step=3, lanes=4):
+            with span("backend.stage", step=3, lanes=4):
+                pass
+    evs = {e[0]: e for e in _host_events(tmp_path, run)}
+    outer, inner = evs["backend.dispatch"], evs["backend.stage"]
+    assert outer[3] == {"step": 3, "lanes": 4}
+    assert outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_span_writes_jsonl_only_given_a_log():
+    log = TraceLog(clock=_fake_clock())
+    with span("engine.prefill", rid=1) as sp:
+        sp["lanes"] = 2                       # a scratch dict, no log
+    assert log.total == 0
+    with span("engine.prefill", log, rid=1) as sp:
+        sp["lanes"] = 2
+    ev, = log.events()
+    assert (ev["ev"], ev["rid"], ev["lanes"], ev["depth"]) == \
+        ("engine.prefill", 1, 2, 0)
+    assert ev["dur_us"] > 0
+
+
+# the program's spans and where each sits: the innermost span around it
+SPAN_PARENTS = {
+    "engine.schedule": {"engine.step"},
+    "engine.prefill": {"engine.step"},
+    "engine.lane_order": {"engine.step"},
+    "engine.sample": {"engine.step"},
+    "backend.flush": {"engine.step", "engine.prefill"},
+    "backend.prefill": {"engine.prefill"},
+    "backend.prefill.wait": {"backend.prefill"},
+    "backend.prefill.fetch": {"backend.prefill"},
+    "backend.prefill.store": {"backend.prefill"},
+    "backend.commit": {"backend.flush"},
+    "backend.commit.fetch": {"backend.commit"},
+    "backend.commit.store": {"backend.commit"},
+    "backend.dispatch": {"engine.step"},
+    "backend.stage": {"backend.dispatch"},
+    "backend.state_pack": {"backend.dispatch"},
+    "backend.launch": {"backend.dispatch"},
+    "backend.decode": {"engine.step"},
+    "backend.decode.wait": {"backend.decode"},
+    "backend.decode.fetch": {"backend.decode"},
+}
+
+
+@pytest.mark.parametrize("arch", ["qwen1_5_0_5b", "hymba_1_5b"])
+def test_served_run_holds_every_span_nested(tmp_path, arch):
+    """A pipelined served run under the profiler: every program span
+    occurs, each inside the span the catalogue says, and ``engine.step``
+    carries the backend's byte counters at entry."""
+    from repro import configs
+    from repro.models import lm
+    from repro.serve.engine import make_paged_lm
+    cfg = configs.get_smoke(arch)
+    params = lm.init(cfg, jax.random.key(0)).params
+    model = make_paged_lm(params, cfg, num_blocks=64, block_size=4,
+                          decode_mode="gather")
+    pool = model.backend.pool
+    eng = ServeEngine(pool, MarsScheduler(pool=pool), model, max_lanes=4)
+    # prompts of whole SSM chunks (8 tokens), one or two of them
+    reqs = [Request(rid=i, prompt=tuple(range(1 + i, 9 + i + 8 * (i % 2))),
+                    arrival=i * 1e-3, max_new=4) for i in range(4)]
+    evs = _host_events(tmp_path, lambda: eng.run(reqs))
+    names = {e[0] for e in evs}
+    want = set(SPAN_PARENTS) | {"engine.step"}
+    if not cfg.has_ssm:
+        want.discard("backend.state_pack")
+    assert names == want
+    for name, s, t, _ in evs:
+        around = [e for e in evs if e[1] <= s and t <= e[2]
+                  and (e[1], e[2]) != (s, t)]
+        if name == "engine.step":
+            assert not around
+            continue
+        parent = max(around, key=lambda e: (e[1], -e[2]))[0]
+        assert parent in SPAN_PARENTS[name], (name, parent)
+    steps = [e[3] for e in evs if e[0] == "engine.step"]
+    assert steps[0]["h2d_bytes"] == 0
+    assert steps[-1]["h2d_bytes"] > 0 and steps[-1]["decode_steps"] > 0
+    assert [st["step_num"] for st in steps] == list(range(len(steps)))
 
 
 def test_trace_ring_drops_oldest_and_counts():

@@ -5,8 +5,9 @@ attached) ``v5e:2x2`` topology from a CPU host, so these tests catch what
 interpret-mode tests never see — a kernel Mosaic refuses — at real
 widths and no chip time.  Nothing runs: each test compiles for one chip
 of the topology and asserts the program holds the kernel
-(``tpu_custom_call``).  ``interpret=False`` is passed explicitly, since
-``jax.default_backend()`` is still the CPU here.
+(``tpu_custom_call``, named ``paged_attention``).  ``interpret=False``
+is passed explicitly, since ``jax.default_backend()`` is still the CPU
+here.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
@@ -70,13 +71,15 @@ def test_paged_attention_compiles_for_v5e(one_chip, no_compile_cache,
                              layer=layer, window=window, interpret=False)
 
     pool = _spec((cfg.n_layers, P, page, K, D), kvd, one_chip)
-    compiled = jax.jit(step).lower(
+    lowered = jax.jit(step).lower(
         _spec((B, H, D), cd, one_chip), _spec((B, K, D), cd, one_chip),
         _spec((B, K, D), cd, one_chip), pool, pool,
         _spec((B, n_pages), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip),
-        _spec((), jnp.int32, one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+        _spec((), jnp.int32, one_chip))
+    # the name the device trace's kernel op carries
+    assert 'kernel_name = "paged_attention"' in lowered.as_text()
+    assert "tpu_custom_call" in lowered.compile().as_text()
 
 
 def test_decode_step_compiles_for_v5e(one_chip, no_compile_cache):
