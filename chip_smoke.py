@@ -40,7 +40,8 @@ def _check(ok: bool, what: str) -> None:
 
 def _compile_decode_step(cfg, lanes: int, pages: int, pool_blocks: int):
     """Compile ``_paged_decode_kernel`` for the default device at the
-    served width, from shapes alone; returns its HLO text."""
+    served width, from shapes alone (the pool as the backend's folded
+    device mirror); returns its HLO text."""
     import jax
     import jax.numpy as jnp
     from repro.kvcache.backend import _paged_decode_kernel
@@ -48,7 +49,7 @@ def _compile_decode_step(cfg, lanes: int, pages: int, pool_blocks: int):
 
     params = jax.eval_shape(lambda: lm.init(cfg, jax.random.key(0)).params)
     kv = jax.ShapeDtypeStruct(
-        (cfg.n_layers, pool_blocks, 16, cfg.n_kv_heads, cfg.d_head),
+        (cfg.n_layers, pool_blocks, 16, cfg.n_kv_heads * cfg.d_head),
         cfg.kvdtype)
     lowered = _paged_decode_kernel.lower(
         params, cfg, jax.ShapeDtypeStruct((lanes, 1), jnp.int32), kv, kv,

@@ -125,10 +125,11 @@ def kv_read_trace_kernel(tables: Sequence, *,
                          window_tokens: int = 0,
                          block_size: int = 16) -> np.ndarray:
     """64B-line addresses of one decode step's KV reads as the Pallas
-    ``paged_attention`` grid issues them: lanes served one after another
-    (grid axis 0), each lane's pages in page-table order (grid axis 1),
-    lines within a page contiguous.  No cross-lane interleave ever reaches
-    the memory system — the kernel-path rendering of the MARS reorder.
+    ``paged_attention`` kernel issues them: lanes served one after
+    another (the grid), each lane's pages in page-table order (the
+    kernel's loop over blocks of pages), lines within a page contiguous.
+    No cross-lane interleave ever reaches the memory system — the
+    kernel-path rendering of the MARS reorder.
 
     ``window_tokens`` > 0 models the kernel's sliding-window page gate: a
     query at position ``num_tokens`` attends cached positions
@@ -152,13 +153,11 @@ def _lane_lines(table, lines_per_block: int, *, window_tokens: int = 0,
     if window_tokens:
         # first valid cached position for the in-flight query (canonical
         # definition: paged_attention ref._window_lo).  A window of 1
-        # admits no cached position (lo == num_tokens), but the kernel's
-        # clamped index map still names one in-range page per lane — the
-        # pipeline DMAs it even though the body never runs — so model a
-        # single residual page, not an empty trace.
+        # admits no cached position (lo == num_tokens): the kernel copies
+        # no page for such a lane.
         lo = table.num_tokens - window_tokens + 1
         if lo >= table.num_tokens:
-            blocks = blocks[-1:]
+            blocks = []
         else:
             blocks = blocks[max(lo, 0) // block_size:]
     base = np.asarray(blocks, np.int64)[:, None] * lines_per_block

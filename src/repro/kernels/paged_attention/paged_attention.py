@@ -3,18 +3,39 @@
 The serving analogue of the paper: a decode batch's KV reads are scattered
 across cache pages ("DRAM rows"); visiting each sequence's pages
 *in page-table order, page-contiguously* turns the gather into sequential
-HBM block reads.  The page table is scalar-prefetched and drives the K/V
-BlockSpec index maps — exactly the PhyPageList head/tail walk.
+HBM block reads.  The page table is scalar-prefetched and names every
+page the kernel copies — exactly the PhyPageList head/tail walk.
 
-Grid: (B, pages_per_seq) with online-softmax state in VMEM scratch across
-the page loop; one query token per sequence (decode).
+**Operand layout.**  The kernel reads the pool *folded*: K and V as
+``(L, P, page, Hkv·D)``, every head of a token side by side in one row.
+That is the layout the TPU gives such an array by default (row-major,
+lanes unpadded when ``Hkv·D`` is a multiple of 128), so the served
+device mirror (``PagedBackend._staged_pages``) is handed over as it is,
+with no relayout.  The unfolded ``(P, page, Hkv, D)`` and
+``(L, P, page, Hkv, D)`` pools the tests and the toy engine keep are
+folded by the wrapper — a copy, off the served path.
 
-The kernel understands the block pool's leading **layer axis**: pass
-``k_pages``/``v_pages`` of shape (L, P, page, Hkv, D) plus ``layer`` and
-the index map reads plane ``layer`` of the pool directly — one block-table
-lookup serves every layer of a row group, and no per-layer plane is ever
-materialized.  4-D pages (single-layer pools, the PR-1 engine) keep
-working unchanged.
+**Grid.**  One grid step per lane, in order; the pool stays in HBM.
+Inside a step the kernel loops over the lane's *live* pages only — from
+the window's first page to the page holding the last cached position —
+in blocks of ``pages_per_step`` pages (``TOKENS_PER_STEP`` tokens),
+double-buffered: block ``k + 1``'s page copies are in flight while
+block ``k`` is attended, and a lane's last block starts the next lane's
+first copies.  Pages beyond the lane's length or outside its sliding
+window are never copied, and a lane with nothing to attend (length 0,
+padded lanes, ``window == 1``) costs no copy at all.
+
+**Every head at once.**  A block is a ``(T, Hkv·D)`` tile of K and of V.
+The query arrives folded too, ``(n_rep, Hkv·D)`` with ``n_rep = H //
+Hkv``, and is spread into a block-diagonal ``(Hp, Hkv·D)`` matrix (query
+head ``h`` keeps only the lanes of its KV head ``h // n_rep``; ``Hp``
+rounds ``H`` up to the sublane tile), so the scores of every head are one
+MXU dot ``(Hp, Hkv·D) × (T, Hkv·D)ᵀ``, and ``p × V`` one more, whose row
+``h`` holds head ``h``'s output in its KV head's lanes.  MHA and GQA are
+the same code.  Precision matches the f32 math: bf16 (or float8) pages
+and queries multiply exactly into f32 accumulators; ``p`` stays f32,
+split into three bf16 parts whose sum is ``p`` exactly before it meets
+V; f32 operands run at ``Precision.HIGHEST``.
 
 ``window`` adds the sliding-window mask: with ``window > 0`` the query
 (the in-flight token at position ``lengths[b]``) attends only cached
@@ -22,8 +43,7 @@ positions in ``(lengths[b] - window, lengths[b])`` — the same keys the
 dense decode mask ``kpos > pos - window`` admits.  ``window`` is a traced
 int32 scalar (scalar-prefetched alongside ``layer``), so a scan over a
 ``global_every`` hybrid's layers can flip it per layer (0 = global) with
-one compiled kernel.  Pages that fall entirely outside the window are
-skipped — never fetched, never touching the DRAM address stream.
+one compiled kernel.
 
 ``decode_attend`` is the full decode-step attention: kernel over the
 cached pages + one online-softmax merge step folding in the in-flight
@@ -45,6 +65,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels import pallas_interpret
 
 NEG_INF = -1e30
+# tokens one block of pages covers (pages_per_step * page), at most
+TOKENS_PER_STEP = 256
+_HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _window_lo(ln, w):
@@ -56,167 +79,299 @@ def _window_lo(ln, w):
     return jnp.where(w > 0, ln - w + 1, 0)
 
 
-def _kernel(pt_ref, len_ref, layer_ref, win_ref, q_ref, k_ref, v_ref,
+def _bf16_exact(dtype) -> bool:
+    """Whether every value of ``dtype`` is a bfloat16 value, so the MXU
+    multiplies it exactly in one bf16 pass."""
+    dtype = jnp.dtype(dtype)
+    return dtype == jnp.bfloat16 or (
+        jnp.issubdtype(dtype, jnp.floating) and dtype.itemsize == 1)
+
+
+def _split3(x):
+    """f32 ``x`` as three bf16 parts whose f32 sum is ``x`` exactly."""
+    hi = x.astype(jnp.bfloat16)
+    r = x - hi.astype(jnp.float32)
+    mid = r.astype(jnp.bfloat16)
+    lo = (r - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _kernel(pt_ref, len_ref, layer_ref, win_ref,
+            gid_ref, q_ref, k_hbm, v_hbm,
             o_ref, m_out_ref, l_out_ref,
-            m_ref, l_ref, acc_ref, *, page: int, n_pages: int,
-            n_rep: int, scale: float):
+            k_buf, v_buf, sems, nxt, m_ref, l_ref, acc_ref, *,
+            page: int, pps: int, n_pages: int, n_rep: int, scale: float):
     b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    ln = len_ref[b]
+    la = layer_ref[0]
     w = win_ref[0]
-    base = j * page
-    # sliding window: the query sits at position ln, so valid cached
-    # positions are [lo, ln) (w = 0 means global, lo <= 0).  The page
-    # gate must admit a page only if it holds at least one valid
-    # position — a fully-masked page would feed
-    # exp(NEG_INF - NEG_INF) = 1 into the softmax state.
-    lo = _window_lo(ln, w)
 
-    @pl.when((base < ln) & (base + page > lo) & (lo < ln))
-    def _body():
-        pos = base + jax.lax.broadcasted_iota(jnp.int32, (n_rep, page), 1)
-        valid = (pos < ln) & (pos >= lo)
-        # GQA: kv head h serves query rows [h*n_rep, (h+1)*n_rep).  Each
-        # head is a pair of 2-D dots read straight from the refs — Mosaic
-        # has no layout for folding (H, D) into (Hkv, n_rep, D) in-kernel,
-        # nor for batched dots over the middle (head) axis of a page.
-        # f32 at HIGHEST keeps the products exact on the MXU (default
-        # precision would round the f32 operands to bf16).
-        for h in range(k_ref.shape[3]):
-            rows = pl.ds(h * n_rep, n_rep)
-            q = q_ref[0, rows, :].astype(jnp.float32)          # (n_rep, D)
-            k = k_ref[0, 0, :, h, :].astype(jnp.float32)       # (page, D)
-            v = v_ref[0, 0, :, h, :].astype(jnp.float32)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(valid, s, NEG_INF)
-            m_prev = m_ref[rows, :]
-            m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m_prev - m_new)
-            l_ref[rows, :] = l_ref[rows, :] * alpha \
-                + p.sum(-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
+    def span(lane):
+        """(lo, ln, first live page, live pages) of ``lane``: the query
+        sits at position ln, so its valid cached positions are [lo, ln)
+        (w = 0 means global, lo <= 0)."""
+        ln = len_ref[lane]
+        lo = _window_lo(ln, w)
+        j0 = jnp.maximum(lo, 0) // page
+        return lo, ln, j0, jnp.where(lo < ln, (ln - 1) // page - j0 + 1, 0)
+
+    lo, ln, j0, n_live = span(b)
+    n_blk = (n_live + pps - 1) // pps
+
+    def copies(lane, j0, n_live, blk, slot):
+        """(live, K copy, V copy) for each page of ``lane``'s block
+        ``blk`` into buffer ``slot``; only live pages are ever copied."""
+        out = []
+        for i in range(pps):
+            j = blk * pps + i
+            pid = pt_ref[lane * n_pages + jnp.minimum(j0 + j, n_pages - 1)]
+            rows = pl.ds(i * page, page)
+            out.append((j < n_live,
+                        pltpu.make_async_copy(k_hbm.at[la, pid],
+                                              k_buf.at[slot, rows],
+                                              sems.at[0, slot]),
+                        pltpu.make_async_copy(v_hbm.at[la, pid],
+                                              v_buf.at[slot, rows],
+                                              sems.at[1, slot])))
+        return out
+
+    def start(cps):
+        for live, kc, vc in cps:
+            @pl.when(live)
+            def _():
+                kc.start()
+                vc.start()
+
+    # the grid runs lanes in order, and a lane's last block starts the
+    # next lane's first copies.  nxt: (this lane's first buffer slot,
+    # whether the lane before already started its copies)
+    @pl.when(b == 0)
+    def _():
+        nxt[0] = 0
+        nxt[1] = 0
+
+    s0 = nxt[0]
+
+    @pl.when((n_blk > 0) & (nxt[1] == 0))
+    def _():
+        start(copies(b, j0, n_live, 0, s0))
+
+    nb = jnp.minimum(b + 1, pl.num_programs(0) - 1)
+    _, _, j0n, n_live_next = span(nb)
+    prefetch_next = (b + 1 < pl.num_programs(0)) & (n_live_next > 0)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    Hp, F = acc_ref.shape
+    T = pps * page
+    # block-diagonal query: row h keeps query head h in the lanes of its
+    # KV head h // n_rep (gid: each lane's KV head) and zeros elsewhere
+    heads = jax.lax.broadcasted_iota(jnp.int32, (Hp, F), 0)
+    gid = gid_ref[...]
+    q = jnp.zeros((Hp, F), jnp.float32)
+    for r in range(n_rep):
+        q = jnp.where(heads == gid * n_rep + r,
+                      q_ref[0, pl.ds(r, 1), :].astype(jnp.float32), q)
+    # bf16 operands multiply exactly in one MXU pass; f32 ones need
+    # HIGHEST
+    if _bf16_exact(q_ref.dtype) and _bf16_exact(k_buf.dtype):
+        qk_dtype, qk_precision = jnp.bfloat16, None
+    else:
+        qk_dtype, qk_precision = jnp.float32, _HIGHEST
+    q = q.astype(qk_dtype)
+
+    def body(blk, carry):
+        slot = (s0 + blk) % 2
+
+        @pl.when(blk + 1 < n_blk)
+        def _():
+            start(copies(b, j0, n_live, blk + 1, 1 - slot))
+
+        @pl.when((blk + 1 == n_blk) & prefetch_next)
+        def _():
+            start(copies(nb, j0n, n_live_next, 0, 1 - slot))
+
+        for i, (live, kc, vc) in enumerate(copies(b, j0, n_live, blk,
+                                                  slot)):
+            @pl.when(live)
+            def _():
+                kc.wait()
+                vc.wait()
+
+            # a page the block does not fill holds no copy: zero its V
+            # rows so that p = 0 there meets finite values
+            @pl.when(jnp.logical_not(live))
+            def _():
+                v_buf[slot, pl.ds(i * page, page), :] = jnp.zeros(
+                    (page, F), v_buf.dtype)
+
+        s = jax.lax.dot_general(
+            q, k_buf[slot].astype(qk_dtype), (((1,), (1,)), ((), ())),
+            precision=qk_precision,
+            preferred_element_type=jnp.float32) * scale      # (Hp, T)
+        pos = (j0 + blk * pps) * page \
+            + jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        s = jnp.where((pos >= lo) & (pos < ln), s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, s.max(-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
+        v = v_buf[slot]
+        if _bf16_exact(v.dtype):
+            # p stays f32: its three bf16 parts, stacked, meet V in one
+            # dot and are summed back in f32
+            pv3 = jax.lax.dot_general(
+                jnp.concatenate(_split3(p), axis=0),
+                v.astype(jnp.bfloat16), (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            acc_ref[rows, :] = acc_ref[rows, :] * alpha + pv
-            m_ref[rows, :] = m_new
+            pv = pv3[:Hp] + pv3[Hp:2 * Hp] + pv3[2 * Hp:]
+        else:
+            pv = jax.lax.dot_general(
+                p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+                precision=_HIGHEST, preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_new
+        return carry
 
-    @pl.when(j == n_pages - 1)
-    def _store():
-        o_ref[0] = (acc_ref[...]
-                    / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
-        m_out_ref[0] = m_ref[...]
-        l_out_ref[0] = l_ref[...]
+    jax.lax.fori_loop(0, n_blk, body, 0)
+    nxt[0] = (s0 + n_blk) % 2
+    nxt[1] = jnp.where((n_blk > 0) & prefetch_next, 1, 0)
+
+    o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)      # (Hp, F)
+    # fold back: row r of the output holds query head g * n_rep + r in
+    # the lanes of KV head g
+    for r in range(n_rep):
+        o_ref[0, pl.ds(r, 1), :] = jnp.sum(
+            jnp.where(heads == gid * n_rep + r, o, 0.0), axis=0,
+            keepdims=True)
+    m_out_ref[0] = m_ref[...]
+    l_out_ref[0] = l_ref[...]
+
+
+def fold_pages(pages):
+    """``(P, page, Hkv, D)`` or ``(L, P, page, Hkv, D)`` pages as the
+    kernel reads them: ``(L, P, page, Hkv·D)`` (a single-layer pool gets
+    ``L = 1``)."""
+    if pages.ndim == 4:
+        pages = pages[None]
+    L, P, page, Hkv, D = pages.shape
+    return pages.reshape(L, P, page, Hkv * D)
 
 
 def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
-                    layer=None, window=0, interpret: bool | None = None,
+                    layer=None, window=0, folded: bool = False,
+                    interpret: bool | None = None,
                     return_state: bool = False):
     """q: (B, H, D); k/v_pages: (P, page, Hkv, D) or, for a layered block
-    pool, (L, P, page, Hkv, D) with ``layer`` selecting the plane;
-    page_tables: (B, n_pages); lengths: (B,).  ``window`` > 0 restricts
-    each query to the last ``window`` positions (query at ``lengths[b]``
-    included); 0 attends all cached positions.
+    pool, (L, P, page, Hkv, D) with ``layer`` selecting the plane; with
+    ``folded`` the pool as the kernel reads it, (L, P, page, Hkv·D)
+    (``fold_pages``; the served device mirror).  page_tables: (B,
+    n_pages); lengths: (B,).  ``window`` > 0 restricts each query to the
+    last ``window`` positions (query at ``lengths[b]`` included); 0
+    attends all cached positions.
 
     Returns (B, H, D), or with ``return_state`` the online-softmax state
-    ``(o, m, l)`` (m/l: (B, H, 1) float32) so a caller can merge more
-    keys — e.g. the decode step's in-flight token — without renormalizing.
-    A lane whose window admits no cached position (length 0, or
-    ``window == 1``) comes back as (o=0, m=-inf, l=0) for the merge.
-    ``interpret=None`` lets the platform decide (``pallas_interpret``).
+    ``(o, m, l)`` (o: (B, H, D) float32, m/l: (B, H, 1) float32) so a
+    caller can merge more keys — e.g. the decode step's in-flight token —
+    without renormalizing.  A lane whose window admits no cached position
+    (length 0, or ``window == 1``) comes back as (o=0, m=-inf, l=0) for
+    the merge.  ``interpret=None`` lets the platform decide
+    (``pallas_interpret``).
     """
     # concrete-value validation must live outside the jit boundary —
     # inside, every operand is a tracer and isinstance checks are dead
-    if k_pages.ndim == 4 and isinstance(layer, (int, np.integer)) \
-            and layer != 0:
+    if not folded and k_pages.ndim == 4 \
+            and isinstance(layer, (int, np.integer)) and layer != 0:
         raise ValueError(
             f"4-D pages have only plane 0, got layer={layer} — a "
             f"calling-convention mix-up (layered pools are 5-D)")
+    if not folded:
+        k_pages, v_pages = fold_pages(k_pages), fold_pages(v_pages)
+        if k_pages.shape[0] == 1:
+            layer = 0
     if interpret is None:
         interpret = pallas_interpret()
-    return _paged_attention(q, k_pages, v_pages, page_tables, lengths,
-                            layer=layer, window=window,
-                            interpret=interpret, return_state=return_state)
+    o, m, l = _paged_attention(q, k_pages, v_pages, page_tables, lengths,
+                               layer=layer, window=window,
+                               interpret=interpret)
+    return (o, m, l) if return_state else o.astype(q.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("interpret", "return_state"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
-                     layer=None, window=0, interpret: bool = False,
-                     return_state: bool = False):
-    B, H, D = q.shape
-    if k_pages.ndim == 4:            # single-layer pool: lift to one plane
-        k_pages = k_pages[None]
-        v_pages = v_pages[None]
-        layer = 0
+                     layer=None, window=0, interpret: bool = False):
     assert layer is not None, "layered k_pages needs a layer index"
-    L, P, page, Hkv, _ = k_pages.shape
-    n_pages = page_tables.shape[1]
+    B, H, D = q.shape
+    L, P, page, F = k_pages.shape
+    Hkv = F // D
     n_rep = H // Hkv
+    n_pages = page_tables.shape[1]
+    pps = max(1, min(n_pages, TOKENS_PER_STEP // page))
+    Hp = -(-H // 16) * 16            # whole bf16 sublane tiles
     scale = 1.0 / np.sqrt(D)
     layer_arr = jnp.atleast_1d(jnp.asarray(layer, jnp.int32))
     win_arr = jnp.atleast_1d(jnp.asarray(window, jnp.int32))
-
-    def kv_index(b, j, pt, ln, la, w):
-        # MARS page walk: the page table drives the block index; the
-        # layer plane comes straight from the layered pool buffer.  The
-        # fetch gate lives HERE, not in the kernel body — a pl.when only
-        # skips compute, the pipeline still DMAs whatever the index map
-        # names.  Clamping j to the lane's valid page range [j0, jmax]
-        # makes every out-of-range grid step re-name the same in-range
-        # block, and Pallas elides the copy when consecutive steps map to
-        # the same block — out-of-window (and beyond-length) pages never
-        # reach the DRAM address stream.
-        lnb = ln[b]
-        lo = _window_lo(lnb, w[0])
-        j0 = jnp.maximum(lo, 0) // page
-        jmax = jnp.maximum(lnb - 1, 0) // page
-        jj = jnp.clip(j, j0, jnp.maximum(jmax, j0))
-        return (la[0], pt[b, jj], 0, 0, 0)
+    # (B, H, D) -> (B, n_rep, Hkv·D): row r holds heads g * n_rep + r
+    qf = q.reshape(B, Hkv, n_rep, D).transpose(0, 2, 1, 3) \
+        .reshape(B, n_rep, F)
+    Fp = -(-F // 128) * 128
+    if Fp != F:
+        # Mosaic copies a page only in whole 128-lane tiles: a folded
+        # width off the tile (hymba's 5 x 64) is padded here, a pool-sized
+        # copy; the served qwen width (16 x 64) is read as it is
+        pad = ((0, 0),) * 3 + ((0, Fp - F),)
+        k_pages, v_pages = jnp.pad(k_pages, pad), jnp.pad(v_pages, pad)
+        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, Fp - F)))
+    lane = jnp.arange(Fp, dtype=jnp.int32)
+    # each lane's KV head; -1 on padding lanes, which no head reads
+    gid = jnp.where(lane < F, lane // D, -1)[None]             # (1, Fp)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(B, n_pages),
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec((1, H, D), lambda b, j, pt, ln, la, w: (b, 0, 0)),
-            pl.BlockSpec((1, 1, page, Hkv, D), kv_index),
-            pl.BlockSpec((1, 1, page, Hkv, D), kv_index),
+            pl.BlockSpec((1, Fp), lambda b, *_: (0, 0)),
+            pl.BlockSpec((1, n_rep, Fp), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec(memory_space=pltpu.HBM),
+            pl.BlockSpec(memory_space=pltpu.HBM),
         ],
         out_specs=[
-            pl.BlockSpec((1, H, D), lambda b, j, pt, ln, la, w: (b, 0, 0)),
-            pl.BlockSpec((1, H, 1), lambda b, j, pt, ln, la, w: (b, 0, 0)),
-            pl.BlockSpec((1, H, 1), lambda b, j, pt, ln, la, w: (b, 0, 0)),
+            pl.BlockSpec((1, n_rep, Fp), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, Hp, 1), lambda b, *_: (b, 0, 0)),
+            pl.BlockSpec((1, Hp, 1), lambda b, *_: (b, 0, 0)),
         ],
-        scratch_shapes=[pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, 1), jnp.float32),
-                        pltpu.VMEM((H, D), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((2, pps * page, Fp), k_pages.dtype),
+            pltpu.VMEM((2, pps * page, Fp), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((Hp, 1), jnp.float32),
+            pltpu.VMEM((Hp, 1), jnp.float32),
+            pltpu.VMEM((Hp, Fp), jnp.float32),
+        ],
     )
     o, m, l = pl.pallas_call(
-        functools.partial(_kernel, page=page, n_pages=n_pages,
+        functools.partial(_kernel, page=page, pps=pps, n_pages=n_pages,
                           n_rep=n_rep, scale=scale),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, H, D), q.dtype),
-                   jax.ShapeDtypeStruct((B, H, 1), jnp.float32),
-                   jax.ShapeDtypeStruct((B, H, 1), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((B, n_rep, Fp), jnp.float32),
+                   jax.ShapeDtypeStruct((B, Hp, 1), jnp.float32),
+                   jax.ShapeDtypeStruct((B, Hp, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_attention",
-    )(page_tables, lengths, layer_arr, win_arr, q, k_pages, v_pages)
-    return (o, m, l) if return_state else o
+    )(page_tables.reshape(-1), lengths, layer_arr, win_arr,
+      gid, qf, k_pages, v_pages)
+    o = o[..., :F].reshape(B, n_rep, Hkv, D).transpose(0, 2, 1, 3) \
+        .reshape(B, H, D)
+    return o, m[:, :H], l[:, :H]
 
 
 def decode_attend(q, k_new, v_new, k_pages, v_pages, page_tables,
-                  lengths, *, layer=0, window=0,
+                  lengths, *, layer=0, window=0, folded: bool = False,
                   interpret: bool | None = None):
     """Decode-step attention: the paged kernel over the cached pages plus
     one online-softmax merge step for the in-flight token (position
@@ -224,7 +379,8 @@ def decode_attend(q, k_new, v_new, k_pages, v_pages, page_tables,
     always inside any sliding window).
 
     q: (B, H, D); k_new/v_new: (B, Hkv, D) — the in-flight token's K/V,
-    not yet written to the pool.  ``window`` > 0 applies the sliding-
+    not yet written to the pool; k/v_pages and ``folded`` as
+    ``paged_attention`` takes them.  ``window`` > 0 applies the sliding-
     window mask to the cached positions.  Returns (B, H, D).
 
     A lane with ``lengths[b] == 0`` degenerates cleanly: the kernel state
@@ -235,7 +391,7 @@ def decode_attend(q, k_new, v_new, k_pages, v_pages, page_tables,
     n_rep = H // Hkv
     scale = 1.0 / np.sqrt(D)
     o, m, l = paged_attention(q, k_pages, v_pages, page_tables, lengths,
-                              layer=layer, window=window,
+                              layer=layer, window=window, folded=folded,
                               interpret=interpret, return_state=True)
     # score of the in-flight token, same GQA head layout as the kernel
     qg = q.reshape(B, Hkv, n_rep, D)
@@ -248,6 +404,5 @@ def decode_attend(q, k_new, v_new, k_pages, v_pages, page_tables,
     p = jnp.exp(s_new - m2)
     l2 = l * alpha + p
     v_rep = jnp.repeat(v_new, n_rep, axis=1).astype(jnp.float32)  # (B,H,D)
-    o2 = (o.astype(jnp.float32) * (l * alpha) + p * v_rep) \
-        / jnp.maximum(l2, 1e-30)
+    o2 = (o * (l * alpha) + p * v_rep) / jnp.maximum(l2, 1e-30)
     return o2.astype(q.dtype)

@@ -97,6 +97,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention.paged_attention import fold_pages
 from repro.kvcache.pool import BlockPool, PoolConfig
 from repro.kvcache.prefix import BlockTable, PrefixCache
 from repro.models.config import ModelConfig
@@ -368,16 +369,16 @@ def _paged_decode(params, cfg, tokens, k_pages, v_pages, page_tables,
     """Gather each lane's pages into a dense per-layer view, run the ragged
     dense decode step, and extract the new token's K/V for write-back.
 
-    k/v_pages: (L, P, page, K, dh); page_tables: (B, n_pages) int32;
-    lengths: (B,) int32 — the padded view always has room for slot
-    ``lengths[b]`` (the backend pads the table before calling).
-    ssm/conv: hybrid side state (L, B, H, P, N) / (L, B, k-1, ch), or
-    None for attention-only families.
+    k/v_pages: the folded device mirror (L, P, page, K·dh); page_tables:
+    (B, n_pages) int32; lengths: (B,) int32 — the padded view always has
+    room for slot ``lengths[b]`` (the backend pads the table before
+    calling).  ssm/conv: hybrid side state (L, B, H, P, N) /
+    (L, B, k-1, ch), or None for attention-only families.
     Returns (logits, k_new (L, B, 1, K, dh), v_new, ssm_new, conv_new).
     """
     from repro.models import lm
     L = k_pages.shape[0]
-    K, dh = k_pages.shape[-2:]
+    K, dh = cfg.n_kv_heads, cfg.d_head
     B = tokens.shape[0]
     k = k_pages[:, page_tables].reshape(L, B, -1, K, dh)
     v = v_pages[:, page_tables].reshape(L, B, -1, K, dh)
@@ -394,8 +395,8 @@ def _paged_decode(params, cfg, tokens, k_pages, v_pages, page_tables,
 def _paged_decode_kernel(params, cfg, tokens, k_pages, v_pages,
                          page_tables, lengths, ssm, conv, interpret=None):
     """Kernel-path decode: per-layer Pallas paged attention straight over
-    the pool's layered page buffers (no dense gather).  Same operand and
-    result shapes as ``_paged_decode``.  ``interpret=None`` lets the
+    the folded device mirror (no dense gather, no relayout).  Same operand
+    and result shapes as ``_paged_decode``.  ``interpret=None`` lets the
     platform decide (``repro.kernels.pallas_interpret``)."""
     from repro.models import lm
     return lm.paged_decode_step(params, cfg, tokens, k_pages, v_pages,
@@ -626,13 +627,18 @@ class PagedBackend:
         pool-sized copies.  ``staged_blocks_last_step`` records how many
         blocks moved — steady-state that is the union of the last two
         steps' dirty sets (one step per slot).  Returns the freshly
-        staged ``(k, v)`` device pair."""
+        staged ``(k, v)`` device pair.
+
+        A mirror is folded, ``(L, P, page, Hkv·dh)``: the layout the
+        device gives it by default and the kernel reads as it is (the
+        host pool's ``(L, P, page, Hkv, dh)`` would be relaid out on the
+        device, whole, at every decode step).  The host reshape is free."""
         pool = self.pool
         if self._mirrors[0] is None:
             pool.drain_dirty()           # full upload covers everything
             for s in (0, 1):
-                self._mirrors[s] = (self._put(pool.k_pages),
-                                    self._put(pool.v_pages))
+                self._mirrors[s] = (self._put(fold_pages(pool.k_pages)),
+                                    self._put(fold_pages(pool.v_pages)))
                 self._slot_dirty[s].clear()
             self.staged_blocks_last_step = pool.cfg.num_blocks
             self._staged_slot, self._slot = 0, 1
@@ -650,8 +656,10 @@ class PagedBackend:
                 idx = self._put(np.asarray(pad, np.int32))
                 k, v = self._mirrors[s]
                 self._mirrors[s] = (
-                    _scatter_blocks(k, idx, self._put(pool.k_pages[:, pad])),
-                    _scatter_blocks(v, idx, self._put(pool.v_pages[:, pad])))
+                    _scatter_blocks(k, idx, self._put(
+                        fold_pages(pool.k_pages[:, pad]))),
+                    _scatter_blocks(v, idx, self._put(
+                        fold_pages(pool.v_pages[:, pad]))))
             self._slot_dirty[s].clear()
             self._staged_slot, self._slot = s, 1 - s
         self.stats.staged_blocks += self.staged_blocks_last_step
@@ -663,7 +671,7 @@ class PagedBackend:
 
     @property
     def _k_dev(self):
-        """K plane of the most recently staged mirror slot (None before
+        """K of the most recently staged mirror slot, folded (None before
         the first stage) — the buffer the next kernel launch reads."""
         return None if self._staged_slot is None \
             else self._mirrors[self._staged_slot][0]

@@ -287,11 +287,11 @@ def paged_attention_apply(p, x, cfg: ModelConfig, *, lengths, k_pages,
     the Pallas ``paged_attention`` kernel (kernel over the cached pages +
     online-softmax merge of the in-flight token).
 
-    x: (B, 1, d); k_pages/v_pages: the pool's layered (L, P, page, K, dh)
-    buffers; ``layer`` selects the plane — one page table serves every
-    layer.  ``window`` > 0 applies the kernel's sliding-window mask (a
-    traced int32, so a scan over a ``global_every`` hybrid's layers flips
-    it per layer).  ``interpret=None`` lets the platform decide
+    x: (B, 1, d); k_pages/v_pages: the pool's folded device mirror
+    (L, P, page, K·dh); ``layer`` selects the plane — one page table
+    serves every layer.  ``window`` > 0 applies the kernel's sliding-
+    window mask (a traced int32, so a scan over a ``global_every``
+    hybrid's layers flips it per layer).  ``interpret=None`` lets the platform decide
     (``repro.kernels.pallas_interpret``).  Returns (out (B, 1, d),
     (k_new, v_new) each (B, 1, K, dh), post-RoPE, for pool write-back
     after the step).
@@ -306,7 +306,7 @@ def paged_attention_apply(p, x, cfg: ModelConfig, *, lengths, k_pages,
     vc = v.astype(cfg.kvdtype).astype(cd)
     o = decode_attend(q[:, 0], kc[:, 0], vc[:, 0], k_pages, v_pages,
                       page_tables, lengths, layer=layer, window=window,
-                      interpret=interpret)
+                      folded=True, interpret=interpret)
     out = jnp.einsum("bshk,hkd->bsd", o[:, None].astype(cd),
                      p["wo"].astype(cd))
     return out, (k, v)

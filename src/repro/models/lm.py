@@ -436,8 +436,8 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, k_pages, v_pages,
     """One-token decode reading cached KV straight from the block pool via
     the Pallas ``paged_attention`` kernel — no gathered dense view.
 
-    tokens: (B, 1) int32; k_pages/v_pages: the pool's layered
-    (L, P, page, K, dh) buffers; page_tables: (B, n_pages) int32;
+    tokens: (B, 1) int32; k_pages/v_pages: the pool's folded device
+    mirror (L, P, page, K·dh); page_tables: (B, n_pages) int32;
     lengths: (B,) int32 ragged per-lane cached token counts.  One page
     table serves every layer (the pool's layer axis = one placement
     decision per block id).  Sliding-window configs run natively: the

@@ -12,6 +12,7 @@ except ImportError:          # property tests skip below; the rest collects
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.paged_attention.paged_attention import (decode_attend,
+                                                           fold_pages,
                                                            paged_attention)
 from repro.kernels.paged_attention.ref import (paged_attention_ref,
                                                paged_decode_ref)
@@ -85,6 +86,10 @@ def test_pallas_interpret_follows_the_platform(monkeypatch):
     (2, 4, 2, 64, 16, 4),
     (3, 8, 1, 64, 32, 2),
     (1, 4, 4, 128, 16, 8),
+    # several blocks of pages a lane, lengths off the block grid: MHA at
+    # qwen's head layout, and GQA at hymba's five query heads per KV head
+    (4, 16, 16, 64, 16, 40),
+    (3, 10, 2, 64, 16, 40),
 ])
 def test_paged_attention_matches_ref(B, H, Hkv, D, page, npages):
     P = B * npages + 2
@@ -127,6 +132,128 @@ def test_paged_attention_layered_pool():
         np.asarray(out4),
         np.asarray(paged_attention_ref(q, kp, vp, pt, lengths, layer=1)),
         rtol=2e-4, atol=2e-4)
+
+
+def test_paged_attention_folded_4d_and_5d_pages_agree():
+    """The served folded mirror (L, P, page, Hkv·D), the layered 5-D pool
+    and a 4-D single plane are one pool to the kernel: the same output,
+    bit for bit."""
+    L, B, H, Hkv, D, page, npages = 3, 3, 8, 2, 64, 16, 20
+    P = B * npages + 1
+    ks = jax.random.split(jax.random.key(17), 3)
+    q = jax.random.normal(ks[0], (B, H, D), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (L, P, page, Hkv, D), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (L, P, page, Hkv, D), jnp.bfloat16)
+    rng = np.random.default_rng(17)
+    pt = jnp.asarray(rng.permutation(P)[:B * npages].reshape(B, npages),
+                     jnp.int32)
+    lengths = jnp.asarray([5, 290, page * npages], jnp.int32)
+    kf, vf = kp.reshape(L, P, page, Hkv * D), vp.reshape(L, P, page, Hkv * D)
+    assert (fold_pages(kp) == kf).all() and fold_pages(kp[1]).shape == \
+        (1, P, page, Hkv * D)
+    five = paged_attention(q, kp, vp, pt, lengths, layer=1, interpret=True)
+    folded = paged_attention(q, kf, vf, pt, lengths, layer=1, folded=True,
+                             interpret=True)
+    four = paged_attention(q, kp[1], vp[1], pt, lengths, interpret=True)
+    np.testing.assert_array_equal(np.asarray(folded, np.float32),
+                                  np.asarray(five, np.float32))
+    np.testing.assert_array_equal(np.asarray(four, np.float32),
+                                  np.asarray(five, np.float32))
+
+
+@pytest.mark.parametrize("H,Hkv", [(16, 16), (10, 2), (25, 5)])
+def test_paged_attention_bf16_is_the_f32_math(H, Hkv):
+    """bf16 pages and queries (the served dtypes) take the one-pass MXU
+    dots with p kept f32 through PV: the result is the f32 oracle on the
+    same values to f32 rounding — not to bf16's.  Covers zero-length and
+    padded lanes (the empty state: o = 0, l = 0, m = -inf), lengths off
+    the page-block grid, MHA, and GQA at 5 query heads per KV head."""
+    L, B, D, page, npages = 2, 6, 64, 16, 40
+    P = B * npages + 1
+    ks = jax.random.split(jax.random.key(18), 3)
+    q = jax.random.normal(ks[0], (B, H, D), jnp.bfloat16)
+    kp = jax.random.normal(ks[1], (L, P, page, Hkv * D), jnp.bfloat16)
+    vp = jax.random.normal(ks[2], (L, P, page, Hkv * D), jnp.bfloat16)
+    rng = np.random.default_rng(18)
+    pt = jnp.asarray(rng.permutation(P)[:B * npages].reshape(B, npages),
+                     jnp.int32)
+    lengths = jnp.asarray([0, 1, 255, 257, 640, 0], jnp.int32)
+    o, m, l = paged_attention(q, kp, vp, pt, lengths, layer=1, folded=True,
+                              interpret=True, return_state=True)
+    assert o.dtype == jnp.float32
+    f32 = lambda x: np.asarray(x, np.float32)
+    ref = paged_attention_ref(
+        q.astype(jnp.float32),
+        kp.astype(jnp.float32).reshape(L, P, page, Hkv, D),
+        vp.astype(jnp.float32).reshape(L, P, page, Hkv, D), pt, lengths,
+        layer=1)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(f32(o)[live], f32(ref)[live],
+                               rtol=1e-5, atol=1e-5)
+    assert (f32(o)[~live] == 0).all() and (f32(l)[~live] == 0).all()
+    assert (f32(m)[~live] <= -1e29).all()
+
+
+@pytest.mark.parametrize("window", [100, 250, 300, 513])
+def test_paged_attention_window_edge_inside_a_page_block(window):
+    """Lanes that span several blocks of pages, with the window's lower
+    edge inside a page and inside a block (not on a block boundary): the
+    kernel starts at the window's first page and masks below the edge."""
+    B, H, Hkv, D, page, npages = 4, 4, 2, 64, 16, 48
+    P = B * npages + 1
+    ks = jax.random.split(jax.random.key(19), 5)
+    q = jax.random.normal(ks[0], (B, H, D))
+    kp = jax.random.normal(ks[1], (P, page, Hkv, D))
+    vp = jax.random.normal(ks[2], (P, page, Hkv, D))
+    kn = jax.random.normal(ks[3], (B, Hkv, D))
+    vn = jax.random.normal(ks[4], (B, Hkv, D))
+    rng = np.random.default_rng(19)
+    pt = jnp.asarray(rng.permutation(P)[:B * npages].reshape(B, npages),
+                     jnp.int32)
+    lengths = jnp.asarray([37, 301, 555, page * npages - 3], jnp.int32)
+    out = paged_attention(q, kp, vp, pt, lengths, window=window,
+                          interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(paged_attention_ref(q, kp, vp, pt, lengths,
+                                       window=window)),
+        rtol=2e-4, atol=2e-4)
+    full = decode_attend(q, kn, vn, kp, vp, pt, lengths, window=window,
+                         interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(full),
+        np.asarray(paged_decode_ref(q, kn, vn, kp, vp, pt, lengths,
+                                    window=window)),
+        rtol=2e-4, atol=2e-4)
+
+
+def test_decode_attend_padded_and_empty_lanes():
+    """A padded batch as the backend sends it: live lanes, lanes of
+    length 0 and padded lanes (length 0, page table of zeros), under a
+    window of 1 on one layer and global on another — each lane attends
+    its cached window and the in-flight token, or the token alone."""
+    L, B, H, Hkv, D, page, npages = 2, 8, 8, 8, 64, 16, 8
+    P = 40
+    ks = jax.random.split(jax.random.key(20), 5)
+    q = jax.random.normal(ks[0], (B, H, D))
+    kp = jax.random.normal(ks[1], (L, P, page, Hkv, D))
+    vp = jax.random.normal(ks[2], (L, P, page, Hkv, D))
+    kn = jax.random.normal(ks[3], (B, Hkv, D))
+    vn = jax.random.normal(ks[4], (B, Hkv, D))
+    rng = np.random.default_rng(20)
+    pt = np.zeros((B, npages), np.int32)
+    pt[:4] = rng.permutation(P)[:4 * npages].reshape(4, npages)
+    lengths = jnp.asarray([17, 0, 128, 100, 0, 0, 0, 0], jnp.int32)
+    kf, vf = fold_pages(kp), fold_pages(vp)
+    for layer, window in ((0, 1), (1, 0)):
+        out = decode_attend(q, kn, vn, kf, vf, jnp.asarray(pt), lengths,
+                            layer=layer, window=window, folded=True,
+                            interpret=True)
+        ref = paged_decode_ref(q, kn, vn, kp, vp, jnp.asarray(pt), lengths,
+                               layer=layer, window=window)
+        assert np.isfinite(np.asarray(out)).all()
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=2e-4, atol=2e-4)
 
 
 def test_decode_attend_merges_inflight_token():

@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from repro import configs
+from repro.kernels.paged_attention.paged_attention import fold_pages
 from repro.kvcache import row_group_of
 from repro.kvcache.backend import DenseBackend, PagedBackend, make_backend
 from repro.models import lm
@@ -67,6 +68,48 @@ def test_dense_paged_decode_parity(arch, decode_mode):
     paged.release()
     paged.pool.check_invariants()
     assert paged.pool.num_live == 0
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+@pytest.mark.parametrize("decode_mode", ["gather", "kernel"])
+def test_device_mirror_is_folded(decode_mode, sharded):
+    """The device mirror a backend stages and hands its decode step is
+    the folded ``(L, P, page, Hkv·dh)`` array — the host pool with each
+    token's heads side by side — and both decode modes read it to the
+    dense backend's logits, on one pool and on two shards."""
+    from repro.kvcache.backend import ShardedPagedBackend
+    cfg, params = _model(ARCHS[0], f32=decode_mode == "kernel")
+    tokens = jax.random.randint(jax.random.key(2), (4, 7), 1, cfg.vocab)
+    dense = DenseBackend(cfg, batch=4, max_seq=24)
+    if sharded:
+        paged = ShardedPagedBackend(cfg, n_shards=2, num_blocks=64,
+                                    block_size=4, decode_mode=decode_mode)
+        backends = paged.backends
+    else:
+        paged = PagedBackend(cfg, num_blocks=64, block_size=4,
+                             decode_mode=decode_mode)
+        backends = [paged]
+    lg_d, _ = lm.prefill(params, cfg, tokens, backend=dense)
+    lm.prefill(params, cfg, tokens, backend=paged)
+    tok = jnp.argmax(lg_d[:, -1], -1).astype(jnp.int32)[:, None]
+    for _ in range(3):
+        lg_d, _ = lm.decode_step(params, cfg, tok, dense)
+        lg_p, _ = lm.decode_step(params, cfg, tok, paged)
+        np.testing.assert_allclose(np.asarray(lg_d, np.float32),
+                                   np.asarray(lg_p, np.float32),
+                                   rtol=1e-4, atol=1e-4)
+        tok = jnp.argmax(lg_d[:, -1], -1).astype(jnp.int32)[:, None]
+    paged.flush()
+    for be in backends:
+        be._staged_pages()                  # stage the last write-back
+        L, P, page = be.pool.k_pages.shape[:3]
+        for dev, host in ((be._k_dev, be.pool.k_pages),
+                          (be._v_dev, be.pool.v_pages)):
+            assert dev.shape == (L, P, page, cfg.n_kv_heads * cfg.d_head)
+            np.testing.assert_array_equal(np.asarray(dev),
+                                          host.reshape(dev.shape))
+    assert all(be.pool.num_live > 0 for be in backends)
+    paged.release()
 
 
 def test_make_backend_registry():
@@ -397,7 +440,7 @@ def test_decode_stages_only_dirty_blocks():
     # the mirror converges to the host pool once pending writes stage
     backend._staged_pages()
     np.testing.assert_array_equal(np.asarray(backend._k_dev),
-                                  pool.k_pages)
+                                  fold_pages(pool.k_pages))
     backend.release()
 
 

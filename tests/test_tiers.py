@@ -12,6 +12,7 @@ try:
 except ImportError:          # property test skips below; the rest collects
     given = settings = st = None
 
+from repro.kernels.paged_attention.paged_attention import fold_pages
 from repro.kvcache import BlockPool, BlockTable, PoolConfig, PrefixCache, \
     ShardedBlockPool, TierManager, TierSpec, row_group_of
 from repro.kvcache.evict import EvictionPolicy
@@ -338,7 +339,8 @@ def test_evicted_dirty_block_never_restaged_plain():
         "freed block id lingering in pool.dirty"
     backend.decode(params, [sid2], [3])
     backend._staged_pages()                     # drain the decode's tail
-    np.testing.assert_array_equal(np.asarray(backend._k_dev), pool.k_pages)
+    np.testing.assert_array_equal(np.asarray(backend._k_dev),
+                                  fold_pages(pool.k_pages))
     backend.release()
     pool.check_invariants()
 
@@ -361,7 +363,7 @@ def test_evicted_dirty_block_never_restaged_sharded():
     backend.decode(params, [sid2], [3])
     backend.backends[0]._staged_pages()         # drain the decode's tail
     np.testing.assert_array_equal(np.asarray(backend.backends[0]._k_dev),
-                                  p0.k_pages)
+                                  fold_pages(p0.k_pages))
     backend.release()
     backend.pool.check_invariants()
 
