@@ -42,9 +42,9 @@ def bench_moe_dispatch(emit):
     x = jax.random.normal(jax.random.key(1), (T, cfg.d_model))
     idx, gates, _ = moe_mod.router_topk(params, x, cfg)
 
+    w_in, w_gate, w_out = moe_mod.expert_stacks(params)
     us_mars = _timeit(jax.jit(lambda x, i, g: ops.mars_moe_ffn(
-        x, i, g, params["w_in"], params["w_gate"], params["w_out"],
-        n_experts=32)), x, idx, gates)
+        x, i, g, w_in, w_gate, w_out, n_experts=32)), x, idx, gates)
     us_base = _timeit(jax.jit(lambda x: moe_mod.moe_apply_einsum(
         params, x, cfg)[0]), x)
     flat = np.asarray(idx).reshape(-1)

@@ -28,10 +28,9 @@ T = 256
 x = jax.random.normal(jax.random.key(1), (T, cfg.d_model))
 idx, gates, _ = moe_mod.router_topk(params, x, cfg)
 
-y_mars = ops.mars_moe_ffn(x, idx, gates, params["w_in"], params["w_gate"],
-                          params["w_out"], n_experts=16)
-y_pallas = ops.mars_moe_ffn(x, idx, gates, params["w_in"],
-                            params["w_gate"], params["w_out"],
+w_in, w_gate, w_out = moe_mod.expert_stacks(params)
+y_mars = ops.mars_moe_ffn(x, idx, gates, w_in, w_gate, w_out, n_experts=16)
+y_pallas = ops.mars_moe_ffn(x, idx, gates, w_in, w_gate, w_out,
                             n_experts=16, use_pallas=True, bm=32)
 y_base, _ = moe_mod.moe_apply_einsum(params, x, cfg)
 np.testing.assert_allclose(np.asarray(y_mars), np.asarray(y_pallas),

@@ -15,6 +15,7 @@ ARCHS = (
     "whisper_base",
     "paligemma_3b",
     "hymba_1_5b",
+    "deepseek_v2_lite",
 )
 
 # CLI ids (--arch) map dashes to underscores

@@ -96,11 +96,20 @@ def _split3(x):
     return hi, mid, lo
 
 
-def _kernel(pt_ref, len_ref, layer_ref, win_ref,
-            gid_ref, q_ref, k_hbm, v_hbm,
-            o_ref, m_out_ref, l_out_ref,
-            k_buf, v_buf, sems, nxt, m_ref, l_ref, acc_ref, *,
-            page: int, pps: int, n_pages: int, n_rep: int, scale: float):
+def _kernel(*refs, page: int, pps: int, n_pages: int, n_rep: int,
+            scale: float, dv: int):
+    """One lane's page walk.  ``dv`` 0: K and V pages (the standard
+    mode).  ``dv`` > 0: the latent mode, one pool of rows whose first
+    ``dv`` lanes are the value — one copy per page serves both."""
+    if dv:
+        (pt_ref, len_ref, layer_ref, win_ref, gid_ref, q_ref, k_hbm,
+         o_ref, m_out_ref, l_out_ref,
+         k_buf, sems, nxt, m_ref, l_ref, acc_ref) = refs
+        v_hbm = v_buf = None
+    else:
+        (pt_ref, len_ref, layer_ref, win_ref, gid_ref, q_ref, k_hbm, v_hbm,
+         o_ref, m_out_ref, l_out_ref,
+         k_buf, v_buf, sems, nxt, m_ref, l_ref, acc_ref) = refs
     b = pl.program_id(0)
     la = layer_ref[0]
     w = win_ref[0]
@@ -129,6 +138,7 @@ def _kernel(pt_ref, len_ref, layer_ref, win_ref,
                         pltpu.make_async_copy(k_hbm.at[la, pid],
                                               k_buf.at[slot, rows],
                                               sems.at[0, slot]),
+                        None if dv else
                         pltpu.make_async_copy(v_hbm.at[la, pid],
                                               v_buf.at[slot, rows],
                                               sems.at[1, slot])))
@@ -139,7 +149,8 @@ def _kernel(pt_ref, len_ref, layer_ref, win_ref,
             @pl.when(live)
             def _():
                 kc.start()
-                vc.start()
+                if vc is not None:
+                    vc.start()
 
     # the grid runs lanes in order, and a lane's last block starts the
     # next lane's first copies.  nxt: (this lane's first buffer slot,
@@ -163,7 +174,8 @@ def _kernel(pt_ref, len_ref, layer_ref, win_ref,
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    Hp, F = acc_ref.shape
+    Hp = acc_ref.shape[0]
+    F = k_buf.shape[-1]
     T = pps * page
     # block-diagonal query: row h keeps query head h in the lanes of its
     # KV head h // n_rep (gid: each lane's KV head) and zeros elsewhere
@@ -197,14 +209,18 @@ def _kernel(pt_ref, len_ref, layer_ref, win_ref,
             @pl.when(live)
             def _():
                 kc.wait()
-                vc.wait()
+                if vc is not None:
+                    vc.wait()
 
             # a page the block does not fill holds no copy: zero its V
-            # rows so that p = 0 there meets finite values
+            # rows (the latent mode's rows) so that p = 0 there meets
+            # finite values
+            val_buf = k_buf if dv else v_buf
+
             @pl.when(jnp.logical_not(live))
             def _():
-                v_buf[slot, pl.ds(i * page, page), :] = jnp.zeros(
-                    (page, F), v_buf.dtype)
+                val_buf[slot, pl.ds(i * page, page), :] = jnp.zeros(
+                    (page, F), val_buf.dtype)
 
         s = jax.lax.dot_general(
             q, k_buf[slot].astype(qk_dtype), (((1,), (1,)), ((), ())),
@@ -218,7 +234,7 @@ def _kernel(pt_ref, len_ref, layer_ref, win_ref,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
-        v = v_buf[slot]
+        v = k_buf[slot, :, :dv] if dv else v_buf[slot]
         if _bf16_exact(v.dtype):
             # p stays f32: its three bf16 parts, stacked, meet V in one
             # dot and are summed back in f32
@@ -240,12 +256,15 @@ def _kernel(pt_ref, len_ref, layer_ref, win_ref,
     nxt[1] = jnp.where((n_blk > 0) & prefetch_next, 1, 0)
 
     o = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)      # (Hp, F)
-    # fold back: row r of the output holds query head g * n_rep + r in
-    # the lanes of KV head g
-    for r in range(n_rep):
-        o_ref[0, pl.ds(r, 1), :] = jnp.sum(
-            jnp.where(heads == gid * n_rep + r, o, 0.0), axis=0,
-            keepdims=True)
+    if dv:
+        o_ref[0] = o                # one KV head: row h is query head h
+    else:
+        # fold back: row r of the output holds query head g * n_rep + r
+        # in the lanes of KV head g
+        for r in range(n_rep):
+            o_ref[0, pl.ds(r, 1), :] = jnp.sum(
+                jnp.where(heads == gid * n_rep + r, o, 0.0), axis=0,
+                keepdims=True)
     m_out_ref[0] = m_ref[...]
     l_out_ref[0] = l_ref[...]
 
@@ -299,34 +318,51 @@ def paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
     return (o, m, l) if return_state else o.astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "scale", "dv"))
 def _paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
-                     layer=None, window=0, interpret: bool = False):
+                     layer=None, window=0, interpret: bool = False,
+                     scale=None, dv: int = 0):
+    """The kernel call.  ``dv`` 0: the standard mode over K and V pages.
+    ``dv`` > 0: the latent mode (``mla_attention``), ``v_pages`` None."""
     assert layer is not None, "layered k_pages needs a layer index"
     B, H, D = q.shape
     L, P, page, F = k_pages.shape
-    Hkv = F // D
-    n_rep = H // Hkv
+    if dv:
+        # one row per token shared by every head; the pool keeps it padded
+        # to whole 128-lane tiles, so only the query is padded here
+        Hkv, n_rep, Fp = 1, H, F
+        assert Fp % 128 == 0 and D <= Fp, (D, Fp)
+        F = D
+    else:
+        Hkv = F // D
+        n_rep = H // Hkv
+        Fp = -(-F // 128) * 128
     n_pages = page_tables.shape[1]
     pps = max(1, min(n_pages, TOKENS_PER_STEP // page))
     Hp = -(-H // 16) * 16            # whole bf16 sublane tiles
-    scale = 1.0 / np.sqrt(D)
+    if scale is None:
+        scale = 1.0 / np.sqrt(D)
     layer_arr = jnp.atleast_1d(jnp.asarray(layer, jnp.int32))
     win_arr = jnp.atleast_1d(jnp.asarray(window, jnp.int32))
     # (B, H, D) -> (B, n_rep, Hkv·D): row r holds heads g * n_rep + r
     qf = q.reshape(B, Hkv, n_rep, D).transpose(0, 2, 1, 3) \
         .reshape(B, n_rep, F)
-    Fp = -(-F // 128) * 128
     if Fp != F:
+        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, Fp - F)))
+    if Fp != k_pages.shape[-1]:
         # Mosaic copies a page only in whole 128-lane tiles: a folded
         # width off the tile (hymba's 5 x 64) is padded here, a pool-sized
         # copy; the served qwen width (16 x 64) is read as it is
         pad = ((0, 0),) * 3 + ((0, Fp - F),)
         k_pages, v_pages = jnp.pad(k_pages, pad), jnp.pad(v_pages, pad)
-        qf = jnp.pad(qf, ((0, 0), (0, 0), (0, Fp - F)))
     lane = jnp.arange(Fp, dtype=jnp.int32)
     # each lane's KV head; -1 on padding lanes, which no head reads
     gid = jnp.where(lane < F, lane // D, -1)[None]             # (1, Fp)
+    pools = (k_pages,) if dv else (k_pages, v_pages)
+    buffers = [pltpu.VMEM((2, pps * page, Fp), a.dtype) for a in pools]
+    o_spec = pl.BlockSpec((1, Hp, dv), lambda b, *_: (b, 0, 0)) if dv \
+        else pl.BlockSpec((1, n_rep, Fp), lambda b, *_: (b, 0, 0))
+    o_shape = (B, Hp, dv) if dv else (B, n_rep, Fp)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
@@ -334,39 +370,38 @@ def _paged_attention(q, k_pages, v_pages, page_tables, lengths, *,
         in_specs=[
             pl.BlockSpec((1, Fp), lambda b, *_: (0, 0)),
             pl.BlockSpec((1, n_rep, Fp), lambda b, *_: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.HBM),
-            pl.BlockSpec(memory_space=pltpu.HBM),
-        ],
+        ] + [pl.BlockSpec(memory_space=pltpu.HBM)] * len(pools),
         out_specs=[
-            pl.BlockSpec((1, n_rep, Fp), lambda b, *_: (b, 0, 0)),
+            o_spec,
             pl.BlockSpec((1, Hp, 1), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec((1, Hp, 1), lambda b, *_: (b, 0, 0)),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((2, pps * page, Fp), k_pages.dtype),
-            pltpu.VMEM((2, pps * page, Fp), v_pages.dtype),
+        scratch_shapes=buffers + [
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((2,), jnp.int32),
             pltpu.VMEM((Hp, 1), jnp.float32),
             pltpu.VMEM((Hp, 1), jnp.float32),
-            pltpu.VMEM((Hp, Fp), jnp.float32),
+            pltpu.VMEM((Hp, dv or Fp), jnp.float32),
         ],
     )
     o, m, l = pl.pallas_call(
         functools.partial(_kernel, page=page, pps=pps, n_pages=n_pages,
-                          n_rep=n_rep, scale=scale),
+                          n_rep=n_rep, scale=scale, dv=dv),
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((B, n_rep, Fp), jnp.float32),
+        out_shape=[jax.ShapeDtypeStruct(o_shape, jnp.float32),
                    jax.ShapeDtypeStruct((B, Hp, 1), jnp.float32),
                    jax.ShapeDtypeStruct((B, Hp, 1), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_attention",
+        name="mla_attention" if dv else "paged_attention",
     )(page_tables.reshape(-1), lengths, layer_arr, win_arr,
-      gid, qf, k_pages, v_pages)
-    o = o[..., :F].reshape(B, n_rep, Hkv, D).transpose(0, 2, 1, 3) \
-        .reshape(B, H, D)
+      gid, qf, *pools)
+    if dv:
+        o = o[:, :H]
+    else:
+        o = o[..., :F].reshape(B, n_rep, Hkv, D).transpose(0, 2, 1, 3) \
+            .reshape(B, H, D)
     return o, m[:, :H], l[:, :H]
 
 
@@ -399,10 +434,53 @@ def decode_attend(q, k_new, v_new, k_pages, v_pages, page_tables,
                        k_new.astype(jnp.float32),
                        precision=jax.lax.Precision.HIGHEST) * scale
     s_new = s_new.reshape(B, H, 1)
+    v_rep = jnp.repeat(v_new, n_rep, axis=1).astype(jnp.float32)  # (B,H,D)
+    return _merge_token(o, m, l, s_new, v_rep).astype(q.dtype)
+
+
+def _merge_token(o, m, l, s_new, v):
+    """One online-softmax merge step: the kernel's state ``(o, m, l)``
+    over the cached pages, then the in-flight token with score ``s_new``
+    (B, H, 1) and value ``v`` (B, H, Dv), float32.  Returns the
+    normalised output (B, H, Dv)."""
     m2 = jnp.maximum(m, s_new)
     alpha = jnp.exp(m - m2)
     p = jnp.exp(s_new - m2)
     l2 = l * alpha + p
-    v_rep = jnp.repeat(v_new, n_rep, axis=1).astype(jnp.float32)  # (B,H,D)
-    o2 = (o * (l * alpha) + p * v_rep) / jnp.maximum(l2, 1e-30)
-    return o2.astype(q.dtype)
+    return (o * (l * alpha) + p * v) / jnp.maximum(l2, 1e-30)
+
+
+def mla_attention(q, pages, page_tables, lengths, *, layer, scale: float,
+                  dv: int, interpret: bool | None = None,
+                  return_state: bool = False):
+    """The kernel's latent mode: absorbed multi-head latent attention, a
+    multi-query attention over one shared row per cached token.
+
+    q: (B, H, W) absorbed queries ``[q_nope W_uk, q_pe]``; pages: the
+    latent pool's device mirror (L, P, page, Wp), each row ``[c, k_pe]``
+    in its first W lanes and zeros up to Wp, a whole number of 128-lane
+    tiles.  Scores are ``q · row * scale``; the value is the row's first
+    ``dv`` lanes (the latent ``c``), so each page is copied once and
+    serves both.  Returns (B, H, dv), or with ``return_state`` the
+    online-softmax state as ``paged_attention`` gives it."""
+    if interpret is None:
+        interpret = pallas_interpret()
+    o, m, l = _paged_attention(q, pages, None, page_tables, lengths,
+                               layer=layer, interpret=interpret,
+                               scale=float(scale), dv=int(dv))
+    return (o, m, l) if return_state else o.astype(q.dtype)
+
+
+def mla_decode_attend(q, row_new, pages, page_tables, lengths, *, layer,
+                      scale: float, dv: int,
+                      interpret: bool | None = None):
+    """Decode-step latent attention: ``mla_attention`` over the cached
+    pages plus one online-softmax merge step for the in-flight token's
+    row ``row_new`` (B, W), not yet in the pool.  Returns (B, H, dv)."""
+    o, m, l = mla_attention(q, pages, page_tables, lengths, layer=layer,
+                            scale=scale, dv=dv, interpret=interpret,
+                            return_state=True)
+    row = row_new.astype(jnp.float32)
+    s_new = jnp.einsum("bhw,bw->bh", q.astype(jnp.float32), row,
+                       precision=_HIGHEST)[..., None] * scale
+    return _merge_token(o, m, l, s_new, row[:, None, :dv]).astype(q.dtype)

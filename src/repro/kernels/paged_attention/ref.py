@@ -97,3 +97,34 @@ def paged_decode_ref(q, k_new, v_new, k_pages, v_pages, page_tables,
                           v.astype(jnp.float32)).astype(qb.dtype)
 
     return jax.vmap(one)(q, k_new, v_new, page_tables, lengths)
+
+
+def mla_attention_ref(q, pages, page_tables, lengths, layer=0, *,
+                      scale: float, dv: int, row_new=None):
+    """Latent-mode oracle (absorbed MLA decode): q (B, H, W) against the
+    rows of a latent pool, (L, P, page, Wp) or (P, page, Wp), whose first
+    W lanes hold ``[c, k_pe]``; the value is a row's first ``dv`` lanes.
+    ``row_new`` (B, W), if given, is the in-flight token, attended after
+    the cached positions.  Returns (B, H, dv) float32."""
+    if pages.ndim == 4:
+        pages = pages[layer]
+    W = q.shape[-1]
+
+    def one(qb, pt, ln, new):
+        rows = pages[pt].reshape(-1, pages.shape[-1])[:, :W]
+        rows = rows.astype(jnp.float32)
+        valid = jnp.arange(rows.shape[0]) < ln
+        if new is not None:
+            rows = jnp.concatenate([rows, new[None].astype(jnp.float32)])
+            valid = jnp.concatenate([valid, jnp.ones(1, bool)])
+        s = jnp.einsum("hw,kw->hk", qb.astype(jnp.float32), rows,
+                       precision=jax.lax.Precision.HIGHEST) * scale
+        s = jnp.where(valid[None, :], s, -1e30)
+        w = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("hk,kv->hv", w, rows[:, :dv],
+                          precision=jax.lax.Precision.HIGHEST)
+
+    if row_new is None:
+        return jax.vmap(lambda qb, pt, ln: one(qb, pt, ln, None))(
+            q, page_tables, lengths)
+    return jax.vmap(one)(q, page_tables, lengths, row_new)

@@ -374,21 +374,29 @@ def _paged_decode(params, cfg, tokens, k_pages, v_pages, page_tables,
     room for slot ``lengths[b]`` (the backend pads the table before
     calling).  ssm/conv: hybrid side state (L, B, H, P, N) /
     (L, B, k-1, ch), or None for attention-only families.
-    Returns (logits, k_new (L, B, 1, K, dh), v_new, ssm_new, conv_new).
+    The latent kind (MLA) passes its mirror as k_pages and None as
+    v_pages.  Returns (logits, k_new (L, B, 1, K, dh), v_new, ssm_new,
+    conv_new, None): no routed-token count on this path.
     """
     from repro.models import lm
     L = k_pages.shape[0]
-    K, dh = cfg.n_kv_heads, cfg.d_head
     B = tokens.shape[0]
-    k = k_pages[:, page_tables].reshape(L, B, -1, K, dh)
-    v = v_pages[:, page_tables].reshape(L, B, -1, K, dh)
+    if cfg.is_mla:
+        # the latent mirror's rows, their tile padding dropped
+        W = cfg.latent_width
+        k = k_pages[:, page_tables][..., :W].reshape(L, B, -1, 1, W)
+        v = None
+    else:
+        K, dh = cfg.n_kv_heads, cfg.d_head
+        k = k_pages[:, page_tables].reshape(L, B, -1, K, dh)
+        v = v_pages[:, page_tables].reshape(L, B, -1, K, dh)
     cache = lm.Cache(k=k, v=v, ssm=ssm, conv=conv, xk=None, xv=None,
                      length=lengths)
     logits, new = lm.dense_decode_step(params, cfg, tokens, cache)
     idx = lengths.astype(jnp.int32)[None, :, None, None, None]
     k_new = jnp.take_along_axis(new.k, idx, axis=2)
-    v_new = jnp.take_along_axis(new.v, idx, axis=2)
-    return logits, k_new, v_new, new.ssm, new.conv
+    v_new = None if v is None else jnp.take_along_axis(new.v, idx, axis=2)
+    return logits, k_new, v_new, new.ssm, new.conv, None
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "interpret"))
@@ -396,8 +404,9 @@ def _paged_decode_kernel(params, cfg, tokens, k_pages, v_pages,
                          page_tables, lengths, ssm, conv, interpret=None):
     """Kernel-path decode: per-layer Pallas paged attention straight over
     the folded device mirror (no dense gather, no relayout).  Same operand
-    and result shapes as ``_paged_decode``.  ``interpret=None`` lets the
-    platform decide (``repro.kernels.pallas_interpret``)."""
+    and result shapes as ``_paged_decode``, and the routed-token count of
+    a model with experts (``lm.paged_decode_step``).  ``interpret=None``
+    lets the platform decide (``repro.kernels.pallas_interpret``)."""
     from repro.models import lm
     return lm.paged_decode_step(params, cfg, tokens, k_pages, v_pages,
                                 page_tables, lengths, ssm_state=ssm,
@@ -410,6 +419,30 @@ def _jit_prefill_parts(params, cfg, tokens):
     return lm.prefill_parts(params, cfg, tokens)
 
 
+def pool_config(cfg: ModelConfig, **kw) -> PoolConfig:
+    """The layered pool ``cfg`` caches into: K and V per KV head, or for
+    the latent kind one row of ``cfg.latent_width`` per token and layer.
+    ``kw``: the pool's sizes and policies."""
+    if cfg.is_mla:
+        heads, dim = 1, cfg.latent_width
+    else:
+        heads, dim = cfg.n_kv_heads, cfg.d_head
+    return PoolConfig(n_kv_heads=heads, head_dim=dim, n_layers=cfg.n_layers,
+                      dtype=str(cfg.kvdtype), kv_kind=cfg.kv_kind, **kw)
+
+
+def mirror_rows(pages: np.ndarray, latent: bool) -> np.ndarray:
+    """Host pool pages ``(L, n, page, Hkv, dh)`` as the device mirror holds
+    them: folded, ``(L, n, page, Hkv·dh)``.  A ``latent`` pool's rows are
+    also padded with zeros to whole 128-lane tiles (576 -> 640), the
+    width the kernel copies a page in; K/V pools keep their width."""
+    rows = fold_pages(pages)
+    if latent and rows.shape[-1] % 128:
+        pad = -rows.shape[-1] % 128
+        rows = np.pad(rows, ((0, 0),) * 3 + ((0, pad),))
+    return rows
+
+
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _scatter_blocks(dev, idx, vals):
     """Write dirty block planes into the device mirror.  The mirror is
@@ -417,6 +450,10 @@ def _scatter_blocks(dev, idx, vals):
     step.  ``idx`` may repeat (pow2 padding); duplicate indices write the
     same value twice, harmlessly."""
     return dev.at[:, idx].set(vals)
+
+
+# the most pool blocks one scatter stages into a device mirror
+STAGE_CHUNK = 512
 
 
 def _pow2(n: int) -> int:
@@ -427,10 +464,13 @@ class BackendStats(StatGroup):
     """What a ``PagedBackend`` moves between host and device: bytes up
     (every upload goes through ``_put``), bytes down (every read-back
     through ``_fetch``), pool blocks staged into the device mirrors, and
-    decode steps dispatched.  Plain counters, always on; adopted as
-    ``backend.<field>`` by ``Observer.attach``."""
+    decode steps dispatched; and, for a model with experts, the live
+    lanes' (token, expert) assignments to the experts it holds, summed
+    over layers and decode steps (``moe_routed_here``, returned by the
+    decode program beside its logits).  Plain counters, always on;
+    adopted as ``backend.<field>`` by ``Observer.attach``."""
     FIELDS = {"h2d_bytes": 0, "d2h_bytes": 0, "staged_blocks": 0,
-              "decode_steps": 0}
+              "decode_steps": 0, "moe_routed_here": 0}
 
 
 @dataclasses.dataclass
@@ -523,16 +563,15 @@ class PagedBackend:
         # ``device`` (see _device_params)
         self._params_src = self._params_dev = None
         self.cfg = cfg
+        want = pool_config(cfg, num_blocks=num_blocks,
+                           block_size=block_size, placement=placement,
+                           eviction=eviction)
         if pool is None:
-            pool = BlockPool(PoolConfig(
-                num_blocks=num_blocks, block_size=block_size,
-                placement=placement, eviction=eviction,
-                n_kv_heads=cfg.n_kv_heads, head_dim=cfg.d_head,
-                n_layers=cfg.n_layers, dtype=str(cfg.kvdtype)))
+            pool = BlockPool(want)
         assert pool.k_pages is not None, "paged backend needs a KV pool"
-        assert pool.cfg.n_layers == cfg.n_layers \
-            and pool.cfg.n_kv_heads == cfg.n_kv_heads \
-            and pool.cfg.head_dim == cfg.d_head, \
+        assert (pool.cfg.n_layers, pool.cfg.n_kv_heads, pool.cfg.head_dim,
+                pool.cfg.kv_kind) == (want.n_layers, want.n_kv_heads,
+                                      want.head_dim, want.kv_kind), \
             "pool KV buffer does not match the model config"
         self.pool = pool
         self.prefix = PrefixCache(pool.cfg.block_size)
@@ -627,18 +666,23 @@ class PagedBackend:
         pool-sized copies.  ``staged_blocks_last_step`` records how many
         blocks moved — steady-state that is the union of the last two
         steps' dirty sets (one step per slot).  Returns the freshly
-        staged ``(k, v)`` device pair.
+        staged ``(k, v)`` device pair (``(rows, None)`` for a latent
+        pool).
 
         A mirror is folded, ``(L, P, page, Hkv·dh)``: the layout the
         device gives it by default and the kernel reads as it is (the
         host pool's ``(L, P, page, Hkv, dh)`` would be relaid out on the
-        device, whole, at every decode step).  The host reshape is free."""
+        device, whole, at every decode step).  The host reshape is free;
+        a latent row is padded on the host to whole lane tiles as it is
+        staged (``mirror_rows``)."""
         pool = self.pool
         if self._mirrors[0] is None:
             pool.drain_dirty()           # full upload covers everything
             for s in (0, 1):
-                self._mirrors[s] = (self._put(fold_pages(pool.k_pages)),
-                                    self._put(fold_pages(pool.v_pages)))
+                self._mirrors[s] = tuple(
+                    None if pages is None
+                    else self._put(mirror_rows(pages, self.cfg.is_mla))
+                    for pages in (pool.k_pages, pool.v_pages))
                 self._slot_dirty[s].clear()
             self.staged_blocks_last_step = pool.cfg.num_blocks
             self._staged_slot, self._slot = 0, 1
@@ -649,17 +693,21 @@ class PagedBackend:
             s = self._slot
             pend = sorted(self._slot_dirty[s])
             self.staged_blocks_last_step = len(pend)
-            if pend:
-                # pad the id list to a power of two (repeating the last
-                # id) so the donated scatter compiles O(log) variants
-                pad = pend + [pend[-1]] * (_pow2(len(pend)) - len(pend))
+            for i in range(0, len(pend), STAGE_CHUNK):
+                # at most STAGE_CHUNK blocks per scatter, so a burst of
+                # long prefills never needs a pool-sized upload beside
+                # the mirrors; each chunk's id list is padded to a power
+                # of two (repeating the last id) so the donated scatter
+                # compiles O(log) variants
+                part = pend[i:i + STAGE_CHUNK]
+                pad = part + [part[-1]] * (_pow2(len(part)) - len(part))
                 idx = self._put(np.asarray(pad, np.int32))
-                k, v = self._mirrors[s]
-                self._mirrors[s] = (
-                    _scatter_blocks(k, idx, self._put(
-                        fold_pages(pool.k_pages[:, pad]))),
-                    _scatter_blocks(v, idx, self._put(
-                        fold_pages(pool.v_pages[:, pad]))))
+                self._mirrors[s] = tuple(
+                    None if dev is None else _scatter_blocks(
+                        dev, idx, self._put(mirror_rows(pages[:, pad],
+                                                      self.cfg.is_mla)))
+                    for dev, pages in zip(self._mirrors[s],
+                                          (pool.k_pages, pool.v_pages)))
             self._slot_dirty[s].clear()
             self._staged_slot, self._slot = s, 1 - s
         self.stats.staged_blocks += self.staged_blocks_last_step
@@ -723,12 +771,13 @@ class PagedBackend:
         self.flush()
         B, S = tokens.shape
         with span("backend.prefill", self._log, shard=self.obs_shard,
-                  rows=B) as sp:
+                  rows=B, kv_kind=self.cfg.kv_kind) as sp:
             logits, parts = _jit_prefill_parts(
                 self._device_params(params), self.cfg,
                 self._put(np.asarray(tokens, np.int32)))
             kvd = self.cfg.kvdtype
-            k_dev, v_dev = parts["k"].astype(kvd), parts["v"].astype(kvd)
+            k_dev = parts["k"].astype(kvd)
+            v_dev = None if parts["v"] is None else parts["v"].astype(kvd)
             state = (parts["ssm"], parts["conv"]) if self.cfg.has_ssm \
                 else None
             with span("backend.prefill.wait", rows=B, tokens=S):
@@ -736,12 +785,13 @@ class PagedBackend:
             with span("backend.prefill.fetch", rows=B, tokens=S):
                 logits = self._fetch(logits)
                 k_all = self._fetch(k_dev)            # (L, B, S, K, dh)
-                v_all = self._fetch(v_dev)
+                v_all = None if v_dev is None else self._fetch(v_dev)
                 ssm_all = conv_all = None
                 if state is not None:
                     ssm_all = np.asarray(self._fetch(state[0]), np.float32)
                     conv_all = self._fetch(state[1])
-            with span("backend.prefill.store", rows=B, tokens=S):
+            with span("backend.prefill.store", rows=B, tokens=S,
+                      kv_kind=self.cfg.kv_kind):
                 sids, shared = self._store_prompts(
                     tokens, k_all, v_all, ssm_all, conv_all, on_alloc)
             sp["shared_tokens"] = int(sum(shared))
@@ -773,7 +823,8 @@ class PagedBackend:
                 table.extend(
                     self.pool, prompt[n:], seq_tokens=prompt,
                     cache=self.prefix if self.share_prefixes else None,
-                    kv=(k_all[:, b, n:], v_all[:, b, n:]))
+                    kv=(k_all[:, b, n:],
+                        None if v_all is None else v_all[:, b, n:]))
             except RuntimeError:
                 # roll back: queued promotions first (their destination
                 # blocks are released with the tables below; the tier
@@ -850,7 +901,8 @@ class PagedBackend:
             blocks.append({
                 "content": pool.content[bid],
                 "k": np.array(pool.k_pages[:, bid]),
-                "v": np.array(pool.v_pages[:, bid]),
+                "v": None if pool.v_pages is None
+                else np.array(pool.v_pages[:, bid]),
             })
         rec = {
             "tokens": list(seq.tokens),
@@ -1027,7 +1079,8 @@ class PagedBackend:
         # out of registers, but shares the padding so both compile alike)
         pt, lengths, toks = ops.decode_step_operands(
             [s.table for s in seqs], tokens, page)
-        with span("backend.stage", step=self._steps, lanes=B):
+        with span("backend.stage", step=self._steps, lanes=B,
+                  kv_kind=self.cfg.kv_kind):
             kp, vp = self._staged_pages()
         if self.obs is not None:
             # live row-locality: this step's page walk in kernel issue
@@ -1046,22 +1099,23 @@ class PagedBackend:
         with span("backend.launch", step=self._steps, lanes=B):
             params = self._device_params(params)
             if self.decode_mode == "kernel":
-                logits, k_new, v_new, ssm_new, conv_new = \
+                logits, k_new, v_new, ssm_new, conv_new, routed = \
                     _paged_decode_kernel(
                         params, self.cfg, self._put(toks), kp, vp,
                         self._put(pt), self._put(lengths), ssm, conv,
                         interpret=self.kernel_interpret)
             else:
-                logits, k_new, v_new, ssm_new, conv_new = _paged_decode(
-                    params, self.cfg, self._put(toks), kp, vp,
-                    self._put(pt), self._put(lengths), ssm, conv)
+                logits, k_new, v_new, ssm_new, conv_new, routed = \
+                    _paged_decode(
+                        params, self.cfg, self._put(toks), kp, vp,
+                        self._put(pt), self._put(lengths), ssm, conv)
         step = DecodeStep(index=self._steps, sids=list(sids),
                           tokens=[int(t) for t in tokens],
                           staged=self.staged_blocks_last_step,
                           batch_api=batch_api, seqs=seqs,
                           on_alloc=on_alloc)
         step.dev.update(logits=logits, k=k_new, v=v_new,
-                        ssm=ssm_new, conv=conv_new)
+                        ssm=ssm_new, conv=conv_new, routed=routed)
         self._steps += 1
         self.stats.decode_steps += 1
         self._inflight = step
@@ -1108,6 +1162,9 @@ class PagedBackend:
             with span("backend.decode.fetch", step=step.index, lanes=B):
                 step.logits = np.asarray(self._fetch(logits)[:B, 0],
                                          np.float32)
+                routed = step.dev.pop("routed", None)
+                if routed is not None:   # computed with the logits
+                    self.stats.moe_routed_here += int(self._fetch(routed))
         # logits landing means the step finished; start the KV transfer
         # for the deferred commit without blocking on it
         for name in ("k", "v", "ssm", "conv"):
@@ -1147,10 +1204,13 @@ class PagedBackend:
             return
         self._pending = None
         B = len(step.sids)
-        with span("backend.commit", step=step.index, lanes=B):
+        with span("backend.commit", step=step.index, lanes=B,
+                  kv_kind=self.cfg.kv_kind):
             with span("backend.commit.fetch", step=step.index, lanes=B):
                 k_new = self._fetch(step.dev.pop("k"))   # (L, Bp, 1, K, dh)
-                v_new = self._fetch(step.dev.pop("v"))
+                v_new = step.dev.pop("v")
+                if v_new is not None:
+                    v_new = self._fetch(v_new)
                 ssm_new = step.dev.pop("ssm")
                 conv_new = step.dev.pop("conv")
                 if ssm_new is not None:
@@ -1175,7 +1235,7 @@ class PagedBackend:
             s.table.extend(
                 self.pool, [int(tok)], seq_tokens=new_tokens,
                 cache=self.prefix if self.share_prefixes else None,
-                kv=(k_new[:, i], v_new[:, i]))
+                kv=(k_new[:, i], None if v_new is None else v_new[:, i]))
             s.tokens = new_tokens     # commit only after the extend
             if ssm_new is not None:
                 s.ssm = np.ascontiguousarray(ssm_new[:, i])
@@ -1329,6 +1389,10 @@ class ShardedPagedBackend:
         """
         from repro.kvcache.sharded_pool import ShardedBlockPool, \
             discover_shards
+        if cfg.is_mla:
+            raise NotImplementedError(
+                "ShardedPagedBackend pages K/V only; the latent KV kind "
+                "(MLA) is served by one PagedBackend")
         if _legacy_pool:
             if len(_legacy_pool) > 1 or pool is not None:
                 raise TypeError(
@@ -1342,10 +1406,9 @@ class ShardedPagedBackend:
             n_shards = discover_shards(n_shards, mesh)
             num_blocks = -(-num_blocks // n_shards) * n_shards
             pool = ShardedBlockPool(
-                PoolConfig(num_blocks=num_blocks, block_size=block_size,
-                           placement=placement, eviction=eviction,
-                           n_kv_heads=cfg.n_kv_heads, head_dim=cfg.d_head,
-                           n_layers=cfg.n_layers, dtype=str(cfg.kvdtype)),
+                pool_config(cfg, num_blocks=num_blocks,
+                            block_size=block_size, placement=placement,
+                            eviction=eviction),
                 n_shards=n_shards, mesh=mesh)
         assert isinstance(pool, ShardedBlockPool), \
             "ShardedPagedBackend needs a ShardedBlockPool"

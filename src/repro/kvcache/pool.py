@@ -78,6 +78,9 @@ class PoolConfig:
     head_dim: Optional[int] = None
     n_layers: int = 1             # leading layer axis of the KV buffer
     dtype: str = "float32"
+    # "kv": K and V buffers; "latent": one buffer of MLA rows (n_kv_heads
+    # 1, head_dim the row width), shared by every head, and no V
+    kv_kind: str = "kv"
 
 
 class PoolStats(StatGroup):
@@ -134,7 +137,8 @@ class BlockPool:
             shape = (cfg.n_layers, n, cfg.block_size,
                      cfg.n_kv_heads, cfg.head_dim)
             self.k_pages = np.zeros(shape, _np_dtype(cfg.dtype))
-            self.v_pages = np.zeros(shape, _np_dtype(cfg.dtype))
+            if cfg.kv_kind != "latent":
+                self.v_pages = np.zeros(shape, _np_dtype(cfg.dtype))
 
     # -- capacity -----------------------------------------------------------
 
@@ -286,7 +290,7 @@ class BlockPool:
 
     # -- KV payload ---------------------------------------------------------
 
-    def write_kv(self, bid: int, offset: int, k, v) -> None:
+    def write_kv(self, bid: int, offset: int, k, v=None) -> None:
         """Write ``t`` token KV rows into a block at ``offset``, for every
         layer plane at once, and mark the block dirty for staging.
 
@@ -296,16 +300,20 @@ class BlockPool:
           offset: first token slot written within the block.
           k, v: (n_layers, t, n_kv_heads, head_dim) arrays; a layerless
             (t, n_kv_heads, head_dim) is accepted when the pool has a
-            single layer plane (the PR-1 single-layer engine path).
+            single layer plane (the PR-1 single-layer engine path).  A
+            latent pool takes its rows as ``k`` and ignores ``v``.
         """
-        k, v = np.asarray(k), np.asarray(v)
+        k = np.asarray(k)
         if k.ndim == 3:
             assert self.cfg.n_layers == 1, "layered pool needs layered KV"
-            k, v = k[None], v[None]
+            k = k[None]
         t = k.shape[1]
         assert offset + t <= self.cfg.block_size
         self.k_pages[:, bid, offset:offset + t] = k
-        self.v_pages[:, bid, offset:offset + t] = v
+        if self.v_pages is not None:
+            v = np.asarray(v)
+            self.v_pages[:, bid, offset:offset + t] = \
+                v[None] if v.ndim == 3 else v
         self.dirty.add(bid)
 
     def copy_block(self, src: int, dst: int) -> None:
@@ -313,7 +321,8 @@ class BlockPool:
         self.content[dst] = self.content[src]
         if self.k_pages is not None:
             self.k_pages[:, dst] = self.k_pages[:, src]
-            self.v_pages[:, dst] = self.v_pages[:, src]
+            if self.v_pages is not None:
+                self.v_pages[:, dst] = self.v_pages[:, src]
             self.dirty.add(dst)
         self.stats.cow_copies += 1
         if self.obs is not None:

@@ -46,7 +46,8 @@ class BlockTable:
 
         ``kv``: optional (k, v) arrays of shape (len(tokens), Hkv, D) —
         or (n_layers, len(tokens), Hkv, D) for a layered pool — to store
-        into the pool's KV buffer alongside the token tags.
+        into the pool's KV buffer alongside the token tags (a latent
+        pool's rows as k, v None).
         """
         bs = pool.cfg.block_size
         assert len(seq_tokens) == self.num_tokens + len(tokens)
@@ -72,7 +73,8 @@ class BlockTable:
                 k, v = kv
                 # token axis is -3 for both layerless and layered shapes
                 pool.write_kv(bid, fill, k[..., done:done + take, :, :],
-                              v[..., done:done + take, :, :])
+                              None if v is None
+                              else v[..., done:done + take, :, :])
             pool.touch(bid)
             self.num_tokens += take
             done += take

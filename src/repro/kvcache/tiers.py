@@ -449,7 +449,8 @@ class TierManager:
             return 0.0               # clean copy below: drop is free
         nbytes = 0
         if self.pool.k_pages is not None:
-            nbytes = self.pool.k_pages[:, bid].nbytes * 2
+            nbytes = self.pool.k_pages[:, bid].nbytes \
+                * (1 if self.pool.v_pages is None else 2)
         cap = sum(max(t.spec.capacity_blocks, 0) for t in self.tiers)
         held = sum(len(t) for t in self.tiers)
         if any(t.spec.capacity_blocks <= 0 for t in self.tiers) \

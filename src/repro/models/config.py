@@ -8,6 +8,7 @@ variant for CPU tests.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Tuple
 
 import jax.numpy as jnp
@@ -38,6 +39,23 @@ class ModelConfig:
     tie_embeddings: bool = False
     # positional fallback when use_rope=False
     max_position: int = 32_768
+    # YaRN RoPE scaling (flat fields keep the config hashable for jit);
+    # rope_factor 1 leaves RoPE unscaled
+    rope_factor: float = 1.0
+    rope_original_max_position: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+
+    # multi-head latent attention (DeepSeek-V2 MLA, no q compression);
+    # kv_lora_rank 0 = standard attention.  Each token caches one row of
+    # kv_lora_rank + qk_rope_head_dim values per layer (the latent kind
+    # of KV), shared by every head.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # MoE
     n_experts: int = 0
@@ -46,7 +64,13 @@ class ModelConfig:
     n_shared_experts: int = 0
     n_dense_layers: int = 0        # leading dense layers (e.g. kimi-k2)
     moe_dense_residual: bool = False  # parallel dense MLP (arctic)
-    router_scale: float = 1.0
+    router_scale: float = 1.0      # routed scaling factor on the gates
+    router_renorm: bool = True     # renormalise the top-k gates to sum 1
+    # expert parallelism: the router's published width, of which this
+    # model holds experts [first_expert, first_expert + n_experts);
+    # 0 = the router is n_experts wide (every expert held)
+    n_routed_experts: int = 0
+    first_expert: int = 0
 
     # SSM (mamba2 / SSD)
     ssm_state: int = 0
@@ -69,6 +93,46 @@ class ModelConfig:
     @property
     def d_head(self) -> int:
         return self.d_model // self.n_heads
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def kv_kind(self) -> str:
+        """What the KV pool holds per token and layer: "kv" (K and V per
+        KV head) or "latent" (one MLA row shared by every head)."""
+        return "latent" if self.is_mla else "kv"
+
+    @property
+    def latent_width(self) -> int:
+        """Values of one cached MLA row: the normed latent, then the
+        shared RoPE key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def router_width(self) -> int:
+        return self.n_routed_experts or self.n_experts
+
+    @property
+    def rope_dim(self) -> int:
+        """Width RoPE rotates: MLA's RoPE part, else the whole head."""
+        return self.qk_rope_head_dim if self.is_mla else self.d_head
+
+    def yarn_magnitude(self, m: float) -> float:
+        """YaRN's magnitude factor ``0.1 m ln(rope_factor) + 1`` (1
+        without scaling).  The softmax scale carries its square at
+        ``rope_mscale_all_dim``; cos and sin carry its ratio at
+        ``rope_mscale`` over ``rope_mscale_all_dim``."""
+        if self.rope_factor <= 1.0 or not m:
+            return 1.0
+        return 0.1 * m * math.log(self.rope_factor) + 1.0
+
+    @property
+    def softmax_scale(self) -> float:
+        dq = self.qk_nope_head_dim + self.qk_rope_head_dim if self.is_mla \
+            else self.d_head
+        return dq ** -0.5 * self.yarn_magnitude(self.rope_mscale_all_dim) ** 2
 
     @property
     def d_inner_ssm(self) -> int:
@@ -106,11 +170,17 @@ class ModelConfig:
             emb += self.max_position * d  # learned positions
         per_attn = d * (self.n_heads + 2 * self.n_kv_heads) * self.d_head \
             + self.n_heads * self.d_head * d
+        if self.is_mla:
+            H, r = self.n_heads, self.kv_lora_rank
+            nope, rope, dv = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                              self.v_head_dim)
+            per_attn = d * H * (nope + rope) + d * (r + rope) + r \
+                + r * H * (nope + dv) + H * dv * d
         per_mlp = d * f * (3 if self.mlp_gated else 2)
         per_moe = 0
         if self.is_moe:
             e = self.d_expert or f
-            per_moe = (d * self.n_experts
+            per_moe = (d * self.router_width
                        + self.n_experts * d * e * 3
                        + self.n_shared_experts * d * e * 3)
         per_ssm = 0
@@ -139,7 +209,7 @@ class ModelConfig:
             return self.n_params()
         d = self.d_model
         e = self.d_expert or self.d_ff
-        inactive = (self.n_experts - self.top_k) * d * e * 3 \
+        inactive = max(self.n_experts - self.top_k, 0) * d * e * 3 \
             * (self.n_layers - self.n_dense_layers)
         return self.n_params() - inactive
 

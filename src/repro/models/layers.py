@@ -9,6 +9,7 @@ abstract initialization (used by the multi-pod dry-run).
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any
 
 import jax
@@ -79,11 +80,40 @@ def apply_norm(p, x, cfg: ModelConfig):
 # Rotary position embedding
 # ---------------------------------------------------------------------------
 
+def yarn_inv_freq(cfg: ModelConfig) -> np.ndarray:
+    """YaRN's inverse frequencies of the ``cfg.rope_dim`` rotated lanes
+    (float64): frequencies above the ``beta_fast`` correction dimension
+    are kept, those below ``beta_slow``'s divided by ``rope_factor``, a
+    linear ramp between."""
+    d, base = cfg.rope_dim, cfg.rope_theta
+    inv = 1.0 / base ** (np.arange(0, d, 2, dtype=np.float64) / d)
+
+    def corr_dim(rotations):
+        return d * math.log(cfg.rope_original_max_position
+                            / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(corr_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(corr_dim(cfg.rope_beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return inv * (1 - ramp) + inv / cfg.rope_factor * ramp
+
+
 def rope_freqs(cfg: ModelConfig, positions: jnp.ndarray) -> tuple:
-    """positions: int32[...]; returns (cos, sin) with trailing dim d_head/2."""
-    d = cfg.d_head
-    inv = 1.0 / (cfg.rope_theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    """positions: int32[...]; returns (cos, sin) with trailing dim
+    ``rope_dim``/2, at YaRN's frequencies and magnitude when
+    ``rope_factor`` > 1."""
+    d = cfg.rope_dim
+    if cfg.rope_factor > 1.0:
+        inv = jnp.asarray(yarn_inv_freq(cfg), jnp.float32)
+    else:
+        inv = 1.0 / (cfg.rope_theta
+                     ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
     ang = positions.astype(jnp.float32)[..., None] * inv
+    mag = cfg.yarn_magnitude(cfg.rope_mscale) \
+        / cfg.yarn_magnitude(cfg.rope_mscale_all_dim)
+    if mag != 1.0:        # DeepSeek-V2's two mscales are equal: none
+        return mag * jnp.cos(ang), mag * jnp.sin(ang)
     return jnp.cos(ang), jnp.sin(ang)
 
 
@@ -101,6 +131,8 @@ def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray):
 # ---------------------------------------------------------------------------
 
 def attention_init(key, cfg: ModelConfig, cross: bool = False) -> ParamBundle:
+    if cfg.is_mla:
+        return mla_init(key, cfg)
     d, H, K, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     ks = jax.random.split(key, 4)
     items = [
@@ -227,6 +259,21 @@ def causal_mask(sq: int, sk: int, window: int = 0, prefix_len=None):
     return m
 
 
+def _cache_write(cache, new, cache_positions):
+    """The dense cache (B, Smax, ...) with ``new`` (B, S, ...) written at
+    ``cache_positions``: None appends, a (B,) vector writes one position
+    per sequence (ragged decode: paged / continuous-batching lanes
+    advance independently), a scalar one position for all."""
+    if cache_positions is None:
+        return jnp.concatenate([cache, new], axis=1)
+    new = new.astype(cache.dtype)
+    if getattr(cache_positions, "ndim", 0):
+        return jax.vmap(lambda c, u, pos: jax.lax.dynamic_update_slice_in_dim(
+            c, u, pos, axis=0))(cache, new, cache_positions)
+    return jax.lax.dynamic_update_slice_in_dim(cache, new, cache_positions,
+                                               axis=1)
+
+
 def attention_apply(p, x, cfg: ModelConfig, *, positions, mask,
                     kv_cache=None, cache_positions=None,
                     xattn_kv=None):
@@ -236,6 +283,10 @@ def attention_apply(p, x, cfg: ModelConfig, *, positions, mask,
       - cross: xattn_kv=(k,v) precomputed from the encoder
     Returns (out, new_kv) where new_kv is (k, v) for cache maintenance.
     """
+    if cfg.is_mla:
+        return mla_attention_apply(p, x, cfg, positions=positions, mask=mask,
+                                   kv_cache=kv_cache,
+                                   cache_positions=cache_positions)
     cd = cfg.cdtype
     H, K = cfg.n_heads, cfg.n_kv_heads
     if xattn_kv is not None:
@@ -249,21 +300,8 @@ def attention_apply(p, x, cfg: ModelConfig, *, positions, mask,
         new_kv = (k, v)
         if kv_cache is not None:
             ck, cv = kv_cache
-            if cache_positions is None:
-                k = jnp.concatenate([ck, k], axis=1)
-                v = jnp.concatenate([cv, v], axis=1)
-            elif getattr(cache_positions, "ndim", 0):
-                # ragged decode: one write position per sequence (paged /
-                # continuous-batching lanes advance independently)
-                upd = jax.vmap(lambda c, u, pos: jax.lax.
-                               dynamic_update_slice_in_dim(c, u, pos, axis=0))
-                k = upd(ck, k.astype(ck.dtype), cache_positions)
-                v = upd(cv, v.astype(cv.dtype), cache_positions)
-            else:
-                k = jax.lax.dynamic_update_slice_in_dim(
-                    ck, k.astype(ck.dtype), cache_positions, axis=1)
-                v = jax.lax.dynamic_update_slice_in_dim(
-                    cv, v.astype(cv.dtype), cache_positions, axis=1)
+            k = _cache_write(ck, k, cache_positions)
+            v = _cache_write(cv, v, cache_positions)
             new_kv = (k, v)
             k = k.astype(cfg.cdtype)   # fp8 cache reads upcast for compute
             v = v.astype(cfg.cdtype)
@@ -310,6 +348,123 @@ def paged_attention_apply(p, x, cfg: ModelConfig, *, lengths, k_pages,
     out = jnp.einsum("bshk,hkd->bsd", o[:, None].astype(cd),
                      p["wo"].astype(cd))
     return out, (k, v)
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2 MLA, no q compression)
+# ---------------------------------------------------------------------------
+
+# queries per step of a long prompt's MLA (see mla_attention_apply)
+MLA_QUERY_CHUNK = 512
+
+
+def mla_init(key, cfg: ModelConfig) -> ParamBundle:
+    """``wq`` (d, H, nope+rope); ``wkv_a`` (d, r+rope) makes the latent
+    ``c`` (normed by ``kv_norm``) and the shared RoPE key; ``w_uk`` (r,
+    H, nope) and ``w_uv`` (r, H, v) expand ``c`` into each head's key and
+    value (DeepSeek's ``kv_b_proj``, split); ``wo`` (H, v, d)."""
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                      cfg.v_head_dim)
+    ks = jax.random.split(key, 5)
+    return _merge(
+        ("wq", _dense_init(ks[0], (d, H, nope + rope),
+                           ("embed", "heads", "head"), cfg.pdtype)),
+        ("wkv_a", _dense_init(ks[1], (d, r + rope), ("embed", "latent"),
+                              cfg.pdtype)),
+        ("kv_norm", ParamBundle({"scale": jnp.ones(r, cfg.pdtype)},
+                                {"scale": ("latent",)})),
+        ("w_uk", _dense_init(ks[2], (r, H, nope),
+                             ("latent", "heads", "head"), cfg.pdtype)),
+        ("w_uv", _dense_init(ks[3], (r, H, dv),
+                             ("latent", "heads", "head"), cfg.pdtype)),
+        ("wo", _dense_init(ks[4], (H, dv, d), ("heads", "head", "embed"),
+                           cfg.pdtype, scale=1.0 / np.sqrt(H * dv))),
+    )
+
+
+def _mla_query(p, x, cfg: ModelConfig, positions):
+    """(q_nope (B,S,H,nope), q_pe (B,S,H,rope) rotated)."""
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cfg.cdtype))
+    nope = cfg.qk_nope_head_dim
+    cos, sin = rope_freqs(cfg, positions)
+    return q[..., :nope], apply_rope(q[..., nope:], cos, sin)
+
+
+def mla_rows(p, x, cfg: ModelConfig, positions):
+    """What the latent pool caches for each token of x (B, S, d): the row
+    ``[c, k_pe]`` (B, S, r+rope), ``c`` after its RMSNorm and ``k_pe``
+    after RoPE."""
+    r = cfg.kv_lora_rank
+    kva = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(cfg.cdtype))
+    c = apply_norm(p["kv_norm"], kva[..., :r], cfg)
+    cos, sin = rope_freqs(cfg, positions)
+    k_pe = apply_rope(kva[..., None, r:], cos, sin)[..., 0, :]
+    return jnp.concatenate([c, k_pe], axis=-1)
+
+
+def mla_attention_apply(p, x, cfg: ModelConfig, *, positions, mask,
+                        kv_cache=None, cache_positions=None):
+    """MLA in its expanded form (training, prefill, dense decode): every
+    head's key ``[c W_uk, k_pe]`` and value ``c W_uv`` are made from the
+    cached rows.  ``kv_cache`` is ``(rows (B, Smax, 1, W), None)``, the
+    latent kind of the dense cache.  Returns (out, (rows (B, S|Smax, 1,
+    W), None))."""
+    cd = cfg.cdtype
+    r, H = cfg.kv_lora_rank, cfg.n_heads
+    q_nope, q_pe = _mla_query(p, x, cfg, positions)
+    rows = mla_rows(p, x, cfg, positions)[:, :, None, :]
+    if kv_cache is not None:
+        rows = _cache_write(kv_cache[0], rows, cache_positions)
+    ctx = rows[:, :, 0].astype(cd)
+    c, k_pe = ctx[..., :r], ctx[..., r:]
+    k_nope = jnp.einsum("bsr,rhn->bshn", c, p["w_uk"].astype(cd))
+    v = jnp.einsum("bsr,rhv->bshv", c, p["w_uv"].astype(cd))
+    k = jnp.concatenate(
+        [k_nope, jnp.broadcast_to(k_pe[:, :, None, :],
+                                  k_nope.shape[:3] + k_pe.shape[-1:])], -1)
+    q = jnp.concatenate([q_nope, q_pe], -1)
+    B, S = q.shape[:2]
+    if kv_cache is None and getattr(mask, "ndim", 0) == 2 \
+            and S > MLA_QUERY_CHUNK and S % MLA_QUERY_CHUNK == 0:
+        # a long prompt's queries a chunk at a time: the float32 scores
+        # of all heads over every query would hold 16 x S^2 x 4 bytes
+        # (1 GiB at 4096 tokens) beside the weights and the pool mirrors
+        n = S // MLA_QUERY_CHUNK
+        qc = q.reshape((B, n, MLA_QUERY_CHUNK) + q.shape[2:]).swapaxes(0, 1)
+        out = jax.lax.map(
+            lambda a: sdpa(a[0], k, v, a[1], scale=cfg.softmax_scale),
+            (qc, mask.reshape(n, MLA_QUERY_CHUNK, S)))
+        out = out.swapaxes(0, 1).reshape((B, S) + out.shape[3:])
+    else:
+        out = sdpa(q, k, v, mask, scale=cfg.softmax_scale)
+    return jnp.einsum("bshv,hvd->bsd", out, p["wo"].astype(cd)), (rows, None)
+
+
+def mla_paged_apply(p, x, cfg: ModelConfig, *, lengths, pages, page_tables,
+                    layer, interpret: bool | None = None):
+    """MLA decode in its absorbed form, reading the latent pool through
+    the kernel's latent mode (``mla_attention``): ``q̃_h = [q_nope_h
+    W_uk,hᵀ, q_pe_h]`` scores the cached rows ``[c, k_pe]``, ``õ_h = Σ
+    p c`` and ``o_h = õ_h W_uv,h``.  x: (B, 1, d); pages: the latent
+    mirror (L, P, page, Wp).  Returns (out (B, 1, d), (row (B, 1, 1, W),
+    None)) for the pool write-back after the step."""
+    from repro.kernels.paged_attention.paged_attention import \
+        mla_decode_attend
+    cd = cfg.cdtype
+    positions = lengths[:, None]
+    q_nope, q_pe = _mla_query(p, x, cfg, positions)
+    rows = mla_rows(p, x, cfg, positions)
+    # the in-flight token sees the rounding the pool applies on write
+    row = rows[:, 0].astype(cfg.kvdtype).astype(cd)
+    q_abs = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], p["w_uk"].astype(cd))
+    q_lat = jnp.concatenate([q_abs, q_pe[:, 0]], axis=-1)
+    o = mla_decode_attend(q_lat, row, pages, page_tables, lengths,
+                          layer=layer, scale=cfg.softmax_scale,
+                          dv=cfg.kv_lora_rank, interpret=interpret)
+    o = jnp.einsum("bhr,rhv->bhv", o.astype(cd), p["w_uv"].astype(cd))
+    out = jnp.einsum("bhv,hvd->bd", o, p["wo"].astype(cd))
+    return out[:, None], (rows[:, :, None, :], None)
 
 
 def cross_kv(p, enc_out, cfg: ModelConfig):

@@ -118,7 +118,13 @@ def _block_apply(bp, x, cfg: ModelConfig, *, masks, positions,
     new_kv = None
     new_ssm = None
     attn_out = None
-    if cfg.has_attention and paged is not None:
+    if cfg.is_mla and paged is not None:
+        h = layers.apply_norm(bp["ln1"], x, cfg)
+        attn_out, new_kv = layers.mla_paged_apply(
+            bp["attn"], h, cfg, lengths=paged["lengths"],
+            pages=paged["k_pages"], page_tables=paged["page_tables"],
+            layer=paged["layer"], interpret=paged["interpret"])
+    elif cfg.has_attention and paged is not None:
         h = layers.apply_norm(bp["ln1"], x, cfg)
         attn_out, new_kv = layers.paged_attention_apply(
             bp["attn"], h, cfg, lengths=paged["lengths"],
@@ -157,7 +163,10 @@ def _block_apply(bp, x, cfg: ModelConfig, *, masks, positions,
         x = x + xo
     if "moe" in bp:
         h = layers.apply_norm(bp["ln2"], x, cfg)
-        mo, aux = moe_mod.moe_apply(bp["moe"], h, cfg)
+        # a paged decode's padded lanes (length 0) route but are not
+        # counted as served tokens
+        valid = None if paged is None else paged["lengths"] > 0
+        mo, aux = moe_mod.moe_apply(bp["moe"], h, cfg, valid=valid)
         if cfg.moe_dense_residual and "mlp" in bp:
             mo = mo + layers.mlp_apply(bp["mlp"], h, cfg)
         x = x + mo
@@ -167,9 +176,23 @@ def _block_apply(bp, x, cfg: ModelConfig, *, masks, positions,
     return x, new_kv, new_ssm, aux
 
 
-def _zero_aux():
-    return {"moe_lb": jnp.zeros((), jnp.float32),
-            "moe_z": jnp.zeros((), jnp.float32)}
+def _zero_aux(cfg: ModelConfig):
+    """Per-layer sums the block scan carries: the MoE aux losses, and for
+    a model with experts ``moe_routed_here``, the (token, expert)
+    assignments that went to the experts this model holds."""
+    aux = {"moe_lb": jnp.zeros((), jnp.float32),
+           "moe_z": jnp.zeros((), jnp.float32)}
+    if cfg.is_moe:
+        aux["moe_routed_here"] = jnp.zeros((), jnp.float32)
+    return aux
+
+
+def _cat_layers(ys_all: dict, name: str, idx: int):
+    """One per-layer output of the leading-dense and main block scans,
+    concatenated over layers; None where neither scan made it."""
+    parts = [ys[name][idx] for ys in ys_all.values()
+             if name in ys and ys[name][idx] is not None]
+    return jnp.concatenate(parts, 0) if parts else None
 
 
 def _scan_blocks(stacked, x, cfg: ModelConfig, *, masks, positions,
@@ -227,7 +250,7 @@ def _scan_blocks(stacked, x, cfg: ModelConfig, *, masks, positions,
         xs["glob"] = glob
     if paged is not None:
         xs["li"] = jnp.asarray(li, jnp.int32)
-    (x, aux), ys = jax.lax.scan(fn, (x, _zero_aux()), xs)
+    (x, aux), ys = jax.lax.scan(fn, (x, _zero_aux(cfg)), xs)
     return x, aux, ys
 
 
@@ -282,7 +305,7 @@ def forward(params, cfg: ModelConfig, tokens, frontend_emb=None,
     masks = (m_window if cfg.sliding_window else m_causal, m_causal)
 
     nd = cfg.n_dense_layers if cfg.is_moe else 0
-    aux_total = _zero_aux()
+    aux_total = _zero_aux(cfg)
     if nd:
         x, aux, _ = _scan_blocks(params["blocks_dense"], x, cfg, masks=masks,
                                  positions=positions, layer_offset=0, n=nd,
@@ -322,7 +345,9 @@ def init_dense_cache(cfg: ModelConfig, batch: int, max_seq: int,
     L, K, dh = cfg.n_layers, cfg.n_kv_heads, cfg.d_head
     cd = cfg.kvdtype
     k = v = ssm = conv = xk = xv = None
-    if cfg.has_attention:
+    if cfg.is_mla:          # the latent kind: one row per token, no V
+        k = jnp.zeros((L, batch, max_seq, 1, cfg.latent_width), cd)
+    elif cfg.has_attention:
         k = jnp.zeros((L, batch, max_seq, K, dh), cd)
         v = jnp.zeros((L, batch, max_seq, K, dh), cd)
     if cfg.has_ssm:
@@ -412,19 +437,11 @@ def dense_decode_step(params, cfg: ModelConfig, tokens, cache: Cache):
     x = layers.apply_norm(params["final_norm"], x, cfg)
     logits = layers.lm_head(params["embed"], x, cfg)
 
-    def _cat(name, idx):
-        parts = []
-        if nd and name in ys_all["dense"]:
-            parts.append(ys_all["dense"][name][idx])
-        if name in ys_all["main"]:
-            parts.append(ys_all["main"][name][idx])
-        return jnp.concatenate(parts, 0) if parts else None
-
     new_cache = Cache(
-        k=_cat("kv", 0) if cfg.has_attention else None,
-        v=_cat("kv", 1) if cfg.has_attention else None,
-        ssm=_cat("ssm", 0) if cfg.has_ssm else None,
-        conv=_cat("ssm", 1) if cfg.has_ssm else None,
+        k=_cat_layers(ys_all, "kv", 0) if cfg.has_attention else None,
+        v=_cat_layers(ys_all, "kv", 1) if cfg.has_attention else None,
+        ssm=_cat_layers(ys_all, "ssm", 0) if cfg.has_ssm else None,
+        conv=_cat_layers(ys_all, "ssm", 1) if cfg.has_ssm else None,
         xk=cache.xk, xv=cache.xv,
         length=cache.length + 1)
     return logits, new_cache
@@ -449,13 +466,20 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, k_pages, v_pages,
     (L, B, k-1, ch) ride alongside the page operands — the PagedBackend
     keeps them per-sequence next to the block tables.
 
-    Returns (logits (B, 1, V), k_new, v_new, ssm_new, conv_new) with
-    k_new/v_new (L, B, 1, K, dh) — the in-flight token's per-layer K/V
-    for the caller's pool write-back (write-after-attend: the kernel
-    never reads a partially-written page) — and ssm_new/conv_new the
-    advanced side state (None for attention-only families).
-    ``interpret=None`` lets the platform decide whether the kernel runs
-    compiled or interpreted (``repro.kernels.pallas_interpret``).
+    Latent-KV (MLA) configs pass the latent pool's mirror (L, P, page,
+    Wp) as ``k_pages`` and None as ``v_pages``; each layer runs the
+    kernel's latent mode (``layers.mla_paged_apply``).
+
+    Returns (logits (B, 1, V), k_new, v_new, ssm_new, conv_new, routed)
+    with k_new/v_new (L, B, 1, K, dh) — the in-flight token's per-layer
+    K/V for the caller's pool write-back (write-after-attend: the kernel
+    never reads a partially-written page; for the latent kind k_new is
+    the row (L, B, 1, 1, W) and v_new None) — ssm_new/conv_new the
+    advanced side state (None for attention-only families), and routed
+    the int32 count of the live lanes' (token, expert) assignments to
+    the experts this model holds, summed over layers (None without
+    experts).  ``interpret=None`` lets the platform decide whether the
+    kernel runs compiled or interpreted (``repro.kernels.pallas_interpret``).
     """
     assert cfg.has_attention and cfg.family not in ("encdec", "vlm"), \
         f"kernel-path decode pages attention KV (+ SSM side state) only " \
@@ -481,28 +505,23 @@ def paged_decode_step(params, cfg: ModelConfig, tokens, k_pages, v_pages,
                                 if ssm_states else None,
                                 paged=paged)
         ys_all["dense"] = ys
-    x, _, ys = _scan_blocks(params["blocks"], x, cfg, masks=None,
-                            positions=positions, layer_offset=nd,
-                            n=cfg.n_layers - nd,
-                            ssm_states=jax.tree.map(
-                                lambda a: a[nd:], ssm_states)
-                            if ssm_states else None,
-                            paged=paged)
+    x, aux, ys = _scan_blocks(params["blocks"], x, cfg, masks=None,
+                              positions=positions, layer_offset=nd,
+                              n=cfg.n_layers - nd,
+                              ssm_states=jax.tree.map(
+                                  lambda a: a[nd:], ssm_states)
+                              if ssm_states else None,
+                              paged=paged)
     ys_all["main"] = ys
 
     x = layers.apply_norm(params["final_norm"], x, cfg)
     logits = layers.lm_head(params["embed"], x, cfg)
+    routed = aux["moe_routed_here"].astype(jnp.int32) if cfg.is_moe \
+        else None
 
-    def _cat(name, idx):
-        parts = []
-        if nd and name in ys_all["dense"]:
-            parts.append(ys_all["dense"][name][idx])
-        if name in ys_all["main"]:
-            parts.append(ys_all["main"][name][idx])
-        return jnp.concatenate(parts, 0) if parts else None
-
-    return (logits, _cat("kv", 0), _cat("kv", 1),
-            _cat("ssm", 0), _cat("ssm", 1))
+    return (logits, _cat_layers(ys_all, "kv", 0),
+            _cat_layers(ys_all, "kv", 1), _cat_layers(ys_all, "ssm", 0),
+            _cat_layers(ys_all, "ssm", 1), routed)
 
 
 def decode_step(params, cfg: ModelConfig, tokens, cache):
@@ -520,7 +539,8 @@ def prefill_parts(params, cfg: ModelConfig, tokens, frontend_emb=None):
     part — the storage-agnostic half of prefill that both backends share.
 
     Returns (logits (B,1,V), parts) with parts:
-      k/v   (L, B, S, K, dh) or None   (post-RoPE, compute dtype)
+      k/v   (L, B, S, K, dh) or None   (post-RoPE, compute dtype); for
+            the latent kind (MLA) k is the rows (L, B, S, 1, W), v None
       ssm   (L, B, H, P, N) or None    conv (L, B, k-1, ch) or None
       xk/xv (L, B, Senc, K, dh) or None
     """
@@ -548,19 +568,11 @@ def prefill_parts(params, cfg: ModelConfig, tokens, frontend_emb=None):
     x = layers.apply_norm(params["final_norm"], x, cfg)
     logits = layers.lm_head(params["embed"], x[:, -1:], cfg)
 
-    def _cat(name, idx):
-        parts = []
-        if nd and name in ys_all.get("dense", {}):
-            parts.append(ys_all["dense"][name][idx])
-        if name in ys_all["main"]:
-            parts.append(ys_all["main"][name][idx])
-        return jnp.concatenate(parts, 0) if parts else None
-
     parts = {
-        "k": _cat("kv", 0) if cfg.has_attention else None,
-        "v": _cat("kv", 1) if cfg.has_attention else None,
-        "ssm": _cat("ssm", 0) if cfg.has_ssm else None,
-        "conv": _cat("ssm", 1) if cfg.has_ssm else None,
+        "k": _cat_layers(ys_all, "kv", 0) if cfg.has_attention else None,
+        "v": _cat_layers(ys_all, "kv", 1) if cfg.has_attention else None,
+        "ssm": _cat_layers(ys_all, "ssm", 0) if cfg.has_ssm else None,
+        "conv": _cat_layers(ys_all, "ssm", 1) if cfg.has_ssm else None,
         "xk": xkv[0] if xkv is not None else None,
         "xv": xkv[1] if xkv is not None else None,
     }
@@ -578,6 +590,7 @@ def dense_prefill(params, cfg: ModelConfig, tokens, max_seq: int,
     if cfg.has_attention:
         cache.k = jax.lax.dynamic_update_slice_in_dim(
             cache.k, parts["k"].astype(cache.k.dtype), 0, axis=2)
+    if cfg.has_attention and not cfg.is_mla:
         cache.v = jax.lax.dynamic_update_slice_in_dim(
             cache.v, parts["v"].astype(cache.v.dtype), 0, axis=2)
     if cfg.has_ssm:

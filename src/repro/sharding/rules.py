@@ -7,6 +7,8 @@ for a concrete mesh.  The production mesh axes are ("pod",) "data", "model":
   TP  : heads / kv_heads / mlp / vocab / ssm_in  -> "model"
   EP  : expert                                   -> "model"
   FSDP: embed (weight rows)                      -> "data"  (ZeRO-3 style)
+  EP+FSDP: expert_embed (a flat expert matrix's
+        expert-major rows, E·d)                  -> ("model", "data")
   DP  : batch                                    -> ("pod", "data")
 
 Explicit input shardings must divide dimensions exactly, so every mapping
@@ -41,6 +43,7 @@ def logical_rules(mesh: jax.sharding.Mesh, fsdp: bool = True) -> dict:
         "kv_heads": "model",
         "mlp": "model",
         "expert": "model",
+        "expert_embed": ("model",) + data_axes if fsdp else "model",
         "ssm_in": "model",
         "ssm_small": None,
         "ssm_heads": "model",

@@ -11,10 +11,11 @@ except ImportError:          # property tests skip below; the rest collects
 
 from repro.kernels.flash_attention.flash_attention import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
-from repro.kernels.paged_attention.paged_attention import (decode_attend,
-                                                           fold_pages,
-                                                           paged_attention)
-from repro.kernels.paged_attention.ref import (paged_attention_ref,
+from repro.kernels.paged_attention.paged_attention import (
+    decode_attend, fold_pages, mla_attention, mla_decode_attend,
+    paged_attention)
+from repro.kernels.paged_attention.ref import (mla_attention_ref,
+                                               paged_attention_ref,
                                                paged_decode_ref)
 from repro.kernels.ssd_scan.ssd_scan import ssd_scan
 from repro.kernels.ssd_scan.ref import ssd_ref
@@ -254,6 +255,55 @@ def test_decode_attend_padded_and_empty_lanes():
         assert np.isfinite(np.asarray(out)).all()
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("H,W,dv,dtype", [
+    # DeepSeek-V2-Lite's row (512 + 64), the mirror padded to 640 lanes
+    (16, 576, 512, jnp.bfloat16),
+    # a smoke-size row (32 + 8), padded to one 128-lane tile
+    (4, 40, 32, jnp.float32),
+])
+def test_mla_attention_matches_ref(H, W, dv, dtype):
+    """The kernel's latent mode (absorbed MLA decode: every head scores
+    one shared row per token, the value is the row's first ``dv`` lanes)
+    against the oracle: an empty lane, one token, lengths off the
+    256-token block grid and pages that straddle a block.  bf16 rows
+    take the same one-pass dots as the standard mode, so the result is
+    the f32 oracle on the same values to f32 rounding.  The in-flight
+    token's merge matches the oracle with the row attended last."""
+    L, B, page, npages = 2, 6, 16, 40
+    Wp = -(-W // 128) * 128
+    P = B * npages + 1
+    ks = jax.random.split(jax.random.key(23), 3)
+    q = jax.random.normal(ks[0], (B, H, W), dtype)
+    rows = jax.random.normal(ks[1], (L, P, page, W), dtype)
+    pages = jnp.pad(rows, ((0, 0),) * 3 + ((0, Wp - W),))
+    rng = np.random.default_rng(23)
+    pt = jnp.asarray(rng.permutation(P)[:B * npages].reshape(B, npages),
+                     jnp.int32)
+    lengths = jnp.asarray([0, 1, 255, 257, 500, 640], jnp.int32)
+    scale = 0.11472
+    f32 = lambda x: np.asarray(x, np.float32)
+    o, m, l = mla_attention(q, pages, pt, lengths, layer=1, scale=scale,
+                            dv=dv, interpret=True, return_state=True)
+    assert o.shape == (B, H, dv) and o.dtype == jnp.float32
+    ref = mla_attention_ref(q.astype(jnp.float32),
+                            pages.astype(jnp.float32), pt, lengths, layer=1,
+                            scale=scale, dv=dv)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(f32(o)[live], f32(ref)[live], rtol=1e-5,
+                               atol=1e-5)
+    assert (f32(o)[~live] == 0).all() and (f32(l)[~live] == 0).all()
+    row_new = jax.random.normal(ks[2], (B, W), dtype)
+    out = mla_decode_attend(q, row_new, pages, pt, lengths, layer=1,
+                            scale=scale, dv=dv, interpret=True)
+    want = mla_attention_ref(q.astype(jnp.float32),
+                             pages.astype(jnp.float32), pt, lengths,
+                             layer=1, scale=scale, dv=dv,
+                             row_new=row_new.astype(jnp.float32))
+    # the merged output is returned in the query's dtype: bf16 rounds it
+    tol = 1e-2 if dtype == jnp.bfloat16 else 1e-5
+    np.testing.assert_allclose(f32(out), f32(want), rtol=tol, atol=tol)
 
 
 def test_decode_attend_merges_inflight_token():

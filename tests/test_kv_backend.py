@@ -444,6 +444,44 @@ def test_decode_stages_only_dirty_blocks():
     backend.release()
 
 
+@pytest.mark.parametrize("latent", [False, True])
+def test_staging_a_burst_in_chunks(monkeypatch, latent):
+    """A burst of dirty blocks larger than ``STAGE_CHUNK`` stages in
+    several scatters of at most that many blocks each; the mirror ends
+    equal to the host pool, for the K/V kind and the latent kind."""
+    from repro.configs.deepseek_v2_lite import smoke
+    from repro.kvcache import backend as backend_mod
+    from repro.kvcache.backend import mirror_rows
+    monkeypatch.setattr(backend_mod, "STAGE_CHUNK", 4)
+    if latent:
+        cfg = smoke()
+        params = lm.init(cfg, jax.random.key(0)).params
+    else:
+        cfg, params = _model(ARCHS[0])
+    backend = PagedBackend(cfg, num_blocks=64, block_size=4,
+                           share_prefixes=False)
+    pool = backend.pool
+    sid, _, _ = backend.new_seq(params, list(range(1, 6)))
+    backend.decode(params, [sid], [3])
+    backend.decode(params, [sid], [4])
+    # two prompts of 9 and 10 blocks land before the next decode
+    sids = [backend.new_seq(params, list(range(10, 10 + n)))[0]
+            for n in (35, 39)]
+    pending = len(set(pool.dirty))
+    scatters = []
+    real = backend_mod._scatter_blocks
+    monkeypatch.setattr(backend_mod, "_scatter_blocks",
+                        lambda dev, idx, vals: scatters.append(
+                            idx.shape[0]) or real(dev, idx, vals))
+    backend.decode(params, [sid] + sids, [5, 6, 7])
+    assert backend.staged_blocks_last_step >= pending > 4
+    assert max(scatters) <= 4 and sum(scatters) >= pending
+    backend._staged_pages()
+    np.testing.assert_array_equal(np.asarray(backend._k_dev),
+                                  mirror_rows(pool.k_pages, latent))
+    backend.release()
+
+
 def test_paged_prefix_sharing_shares_storage():
     cfg, params = _model(ARCHS[0])
     backend = PagedBackend(cfg, num_blocks=64, block_size=4)

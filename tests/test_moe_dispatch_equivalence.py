@@ -1,5 +1,6 @@
 """All MoE dispatch paths compute the same function (in f32):
-dense oracle == einsum baseline == MARS local == kernels op."""
+dense oracle == einsum baseline == MARS local (also for one chip's share
+of the experts) == kernels op."""
 import dataclasses
 
 import jax
@@ -25,13 +26,18 @@ def setup():
     return cfg, params, x, idx, gates
 
 
-def _dense_oracle(params, x, idx, gates):
+def _dense_oracle(params, x, idx, gates, first=0):
+    """Every held expert on every token, then each token's routed ones
+    picked (router ids ``first ..`` are the held ones; others add 0)."""
     T = x.shape[0]
-    h = jnp.einsum("td,edf->tef", x, params["w_in"])
-    g = jnp.einsum("td,edf->tef", x, params["w_gate"])
-    o = jnp.einsum("tef,efd->ted", jax.nn.silu(g) * h, params["w_out"])
-    per = o[jnp.arange(T)[:, None], idx]
-    return (per * gates[..., None]).sum(1)
+    w_in, w_gate, w_out = moe_mod.expert_stacks(params)
+    h = jnp.einsum("td,edf->tef", x, w_in)
+    g = jnp.einsum("td,edf->tef", x, w_gate)
+    o = jnp.einsum("tef,efd->ted", jax.nn.silu(g) * h, w_out)
+    local = idx - first
+    held = (local >= 0) & (local < w_in.shape[0])
+    per = o[jnp.arange(T)[:, None], jnp.where(held, local, 0)]
+    return (per * (gates * held)[..., None]).sum(1)
 
 
 def test_einsum_matches_dense(setup):
@@ -50,11 +56,28 @@ def test_mars_local_matches_dense(setup):
                                rtol=2e-5, atol=2e-5)
 
 
+def test_mars_local_share_matches_dense(setup):
+    """One chip's share: experts 4-7 of a router over 16.  Assignments
+    to the experts held elsewhere add nothing and are not counted."""
+    cfg, _, x, _, _ = setup
+    share = dataclasses.replace(cfg, n_experts=4, n_routed_experts=16,
+                                first_expert=4)
+    params = moe_mod.moe_init(jax.random.key(2), share).params
+    idx, gates, _ = moe_mod.router_topk(params, x, share)
+    want = _dense_oracle(params, x, idx, gates, first=4)
+    got, aux = moe_mod._mars_dispatch_local(params, x, share)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    here = (np.asarray(idx) >= 4) & (np.asarray(idx) < 8)
+    np.testing.assert_array_equal(np.asarray(aux["held"]), here.reshape(-1))
+    assert 0 < here.sum() < here.size
+
+
 def test_kernel_op_matches_dense(setup):
     cfg, params, x, idx, gates = setup
     want = _dense_oracle(params, x, idx, gates)
-    got = ops.mars_moe_ffn(x, idx, gates, params["w_in"], params["w_gate"],
-                           params["w_out"], n_experts=cfg.n_experts)
+    got = ops.mars_moe_ffn(x, idx, gates, *moe_mod.expert_stacks(params),
+                           n_experts=cfg.n_experts)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 

@@ -5,7 +5,8 @@ attached) ``v5e:2x2`` topology from a CPU host, so these tests catch what
 interpret-mode tests never see — a kernel Mosaic refuses — at real
 widths and no chip time.  Nothing runs: each test compiles for one chip
 of the topology and asserts the program holds the kernel
-(``tpu_custom_call``, named ``paged_attention``).  ``interpret=False``
+(``tpu_custom_call``, named ``paged_attention``, or ``mla_attention``
+for the latent mode).  ``interpret=False``
 is passed explicitly, since ``jax.default_backend()`` is still the CPU
 here.
 
@@ -13,12 +14,18 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import re
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from repro import configs
-from repro.kernels.paged_attention.paged_attention import decode_attend
+from repro.configs.deepseek_v2_lite import CONFIG as DSV2_LITE, \
+    expert_share
+from repro.kernels.paged_attention.paged_attention import decode_attend, \
+    mla_decode_attend
 from repro.kvcache.backend import _paged_decode_kernel
 from repro.models import lm
 
@@ -88,18 +95,44 @@ def test_paged_attention_compiles_for_v5e(one_chip, no_compile_cache,
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
-def _compiled_decode_step(one_chip, B, n_pages, P):
-    """HLO of the whole jitted kernel-path decode step at qwen1.5-0.5B's
-    full width (24 layers, vocab 151,936), parameters as shapes only and
-    the pool as the backend's folded device mirror."""
-    cfg = configs.get("qwen1_5_0_5b")
+def test_mla_attention_compiles_for_v5e(one_chip, no_compile_cache):
+    """The latent mode at DeepSeek-V2-Lite's widths: 16 heads' absorbed
+    queries (576 wide) over the latent mirror, whose rows the backend
+    pads to 640 lanes, and the in-flight row's merge."""
+    cfg = DSV2_LITE
+    B, page, P, n_pages = 8, 16, 256, 8
+    H, W = cfg.n_heads, cfg.latent_width
+
+    def step(q, row, pages, pt, lengths, layer):
+        return mla_decode_attend(q, row, pages, pt, lengths, layer=layer,
+                                 scale=cfg.softmax_scale,
+                                 dv=cfg.kv_lora_rank, interpret=False)
+
+    lowered = jax.jit(step).lower(
+        _spec((B, H, W), cfg.cdtype, one_chip),
+        _spec((B, W), cfg.cdtype, one_chip),
+        _spec((cfg.n_layers, P, page, 640), cfg.kvdtype, one_chip),
+        _spec((B, n_pages), jnp.int32, one_chip),
+        _spec((B,), jnp.int32, one_chip), _spec((), jnp.int32, one_chip))
+    assert 'kernel_name = "mla_attention"' in lowered.as_text()
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+def _compiled_decode_step(one_chip, B, n_pages, P, cfg=None):
+    """HLO of the whole jitted kernel-path decode step at full width
+    (qwen1.5-0.5B's 24 layers and 151,936-word vocabulary unless ``cfg``
+    says otherwise), parameters as shapes only and the pool as the
+    backend's folded device mirror (for the latent kind its one buffer
+    of rows padded to 640 lanes, and no V)."""
+    cfg = cfg or configs.get("qwen1_5_0_5b")
     params = jax.tree.map(
         lambda a: _spec(a.shape, a.dtype, one_chip),
         jax.eval_shape(lambda: lm.init(cfg, jax.random.key(0)).params))
-    pool = _spec((cfg.n_layers, P, 16, cfg.n_kv_heads * cfg.d_head),
-                 cfg.kvdtype, one_chip)
+    width = 640 if cfg.is_mla else cfg.n_kv_heads * cfg.d_head
+    pool = _spec((cfg.n_layers, P, 16, width), cfg.kvdtype, one_chip)
     return _paged_decode_kernel.lower(
-        params, cfg, _spec((B, 1), jnp.int32, one_chip), pool, pool,
+        params, cfg, _spec((B, 1), jnp.int32, one_chip), pool,
+        None if cfg.is_mla else pool,
         _spec((B, n_pages), jnp.int32, one_chip),
         _spec((B,), jnp.int32, one_chip), None, None,
         interpret=False).compile().as_text()
@@ -124,3 +157,29 @@ def test_decode_step_reads_the_pool_without_a_copy(one_chip,
     copies = [ln for ln in hlo.splitlines()
               if " copy(" in ln and pool in ln.split("copy(")[0]]
     assert not copies, copies[0][:200]
+
+
+def test_latent_decode_step_reads_the_pool_without_a_copy(one_chip,
+                                                           no_compile_cache):
+    """DeepSeek-V2-Lite's decode step as the cell serves it (one chip's
+    share of 8 experts, 32 lanes, 512 pages, a pool of 5000 blocks): the
+    latent mirror goes to the ``mla_attention`` kernel as it is, with no
+    ``copy`` of a pool-shaped array; and the grouped matmul reads the
+    flat expert-major expert matrices as stored, with no copy or
+    transpose of one (a (d, E, e) layout is relaid out at every step)."""
+    hlo = _compiled_decode_step(one_chip, 32, 512, 5000,
+                                expert_share(DSV2_LITE, 8, 0))
+    assert "tpu_custom_call" in hlo
+    pool = "bf16[27,5000,16,640]"
+    assert pool in hlo
+    copies = [ln for ln in hlo.splitlines()
+              if " copy(" in ln and pool in ln.split("copy(")[0]]
+    assert not copies, copies[0][:200]
+    moved = []
+    for ln in hlo.splitlines():
+        m = re.search(r"= bf16\[([\d,]+)\]\S* (copy|transpose)\(", ln)
+        if m and "1408" in m.group(1).split(",") and np.prod(
+                [int(n) for n in m.group(1).split(",")]) >= 2048 * 1408:
+            moved.append(ln)
+    assert not moved, moved[0][:200]
+
