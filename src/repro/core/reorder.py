@@ -12,11 +12,24 @@ with no data-dependent shapes, hence jit/pjit friendly.
 This module is the bridge between the paper-faithful simulator and the
 TPU-native kernels: ``kernels/moe_dispatch``, ``kernels/mars_gather``,
 ``serving/scheduler`` and ``data/pipeline`` all consume these functions.
+
+The emission order has two renderings of one permutation.  ``mars_order``
+is the jax one, for streams that live on the device inside a program
+(and the DRAM-model replays of ``benchmarks/kvcache_bench.py``).
+``mars_order_host`` is the numpy one, for the short streams the host
+orders between programs: the engine's decode lanes
+(``kernels.paged_attention.ops.batch_lane_order``) and a tier promotion
+batch (``kvcache.tiers.promotion_order``).  Run eagerly on tens of ints,
+the jax rendering would dispatch each of its primitives as a device
+program of its own.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def mars_order(page_ids: jnp.ndarray, *, num_pages: int | None = None,
@@ -46,6 +59,24 @@ def mars_order(page_ids: jnp.ndarray, *, num_pages: int | None = None,
         base = (jnp.arange(wperm.shape[0]) * window)[:, None]
         return (wperm + base).reshape(-1)[:n]
     return _mars_order_full(page_ids, num_pages)
+
+
+def mars_order_host(keys: Sequence[int] | np.ndarray) -> np.ndarray:
+    """``mars_order``'s permutation (no window), computed on the host.
+
+    A stable sort by each key's first arrival: keys grouped, groups in
+    first-arrival order, FIFO within a group.  Returns int32, equal to
+    ``np.asarray(mars_order(keys))`` for any int32 keys, ``-1`` included.
+
+    >>> mars_order_host([3, 1, 3, 1, 2]).tolist()
+    [0, 2, 1, 3, 4]
+    """
+    keys = np.asarray(keys)
+    if keys.size == 0:
+        return np.zeros(0, np.int32)
+    _, first, inverse = np.unique(keys, return_index=True,
+                                  return_inverse=True)
+    return np.argsort(first[inverse], kind="stable").astype(np.int32)
 
 
 def _mars_order_full(page_ids: jnp.ndarray, num_pages: int | None) -> jnp.ndarray:

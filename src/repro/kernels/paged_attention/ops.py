@@ -13,8 +13,10 @@ Views of the same object:
                            ``backend.PagedBackend.dispatch_decode`` hands
                            the jitted decode
   ``batch_lane_order``     order decode lanes so sequences whose tail blocks
-                           share a DRAM row neighborhood sit adjacent — the
-                           ``reorder.mars_order`` policy applied to the batch
+                           share a DRAM row neighborhood (``lane_keys``) sit
+                           adjacent — the MARS emission order applied to the
+                           batch, on the host (``reorder.mars_order_host``:
+                           no device program between two decode steps)
   ``kv_read_trace``        the 64B-line address stream the paged *gather*
                            emits toward memory (per-lane streams interleaved
                            by the parallel gather), consumable by
@@ -34,7 +36,7 @@ from typing import Sequence
 
 import numpy as np
 
-from repro.core.reorder import mars_order
+from repro.core.reorder import mars_order_host
 from repro.core.streams import _round_robin_merge
 from repro.kvcache.placement import row_group_of
 from repro.kvcache.pool import LINES_PER_BLOCK
@@ -82,26 +84,35 @@ def decode_step_operands(tables: Sequence, tokens: Sequence[int],
     return pt, lengths, toks
 
 
-def batch_lane_order(tables: Sequence, blocks_per_group: int,
-                     shard_ids: Sequence[int] | None = None) -> np.ndarray:
-    """Permutation over batch lanes grouping tail blocks by row neighborhood
-    (first-arrival page order, FIFO within a page — ``mars_order``).
+def lane_keys(tables: Sequence, blocks_per_group: int,
+              shard_ids: Sequence[int] | None = None) -> np.ndarray:
+    """int32 grouping key of each decode lane: the row neighborhood of its
+    tail block, ``-1`` for a table that holds no block.
 
     ``shard_ids`` (mesh-sharded pools): per-lane shard of the lane's pool
     — block ids are shard-local, so the grouping key gets the leading
     shard coordinate of ``placement.placement_key``; lanes on different
     shards never share a neighborhood even when their local ids collide.
     """
-    if not tables:
-        return np.zeros(0, np.int64)
     groups = np.asarray([
         row_group_of(t.blocks[-1], blocks_per_group) if t.blocks else -1
         for t in tables], np.int32)
-    if shard_ids is not None:
+    if shard_ids is not None and len(tables):
         assert len(shard_ids) == len(tables)
         span = int(groups.max()) + 2        # local groups live in [-1, max]
         groups = np.asarray(shard_ids, np.int32) * span + groups
-    return np.asarray(mars_order(groups))
+    return groups
+
+
+def batch_lane_order(tables: Sequence, blocks_per_group: int,
+                     shard_ids: Sequence[int] | None = None) -> np.ndarray:
+    """Permutation over batch lanes grouping tail blocks by row neighborhood
+    (first-arrival page order, FIFO within a page) over ``lane_keys``,
+    computed on the host by ``reorder.mars_order_host`` — the permutation
+    jax's ``mars_order`` gives, without a device program per primitive on
+    every engine step.
+    """
+    return mars_order_host(lane_keys(tables, blocks_per_group, shard_ids))
 
 
 def kv_read_trace(tables: Sequence, *, grant_beats: int = 4,

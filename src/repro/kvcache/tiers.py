@@ -13,7 +13,8 @@ batched prefill (``TierManager.match`` enqueues, the owning backend
 flushes once per batch) and the batched copy-in is MARS-reordered by
 **destination row group** — group writes by DRAM row neighborhood,
 groups in first-arrival order, FIFO within a group (``promotion_order``
-is the numpy rendering of ``core.reorder.mars_order``).  The destination
+is ``core.reorder.mars_order_host``, the host rendering of
+``mars_order``).  The destination
 blocks are MARS-placed against the requesting sequence's blocks, so the
 reordered copy-in stream is row-contiguous where the arrival-interleaved
 stream is not — ``benchmarks/kvcache_bench.py`` replays both through
@@ -27,8 +28,8 @@ for one that would have to be recomputed — instead of pure recency.
 ``TierManager`` installs the scoring hook on pools configured with
 ``eviction="cost"``.
 
-Kept numpy-only (like the rest of the allocator layer) so it is
-importable without jax; the jax-facing wiring lives in
+Kept to numpy on the host (like the rest of the allocator layer): it
+runs no device program; the jax-facing wiring lives in
 ``kvcache.backend``.
 
 >>> from repro.kvcache.pool import BlockPool, PoolConfig
@@ -53,6 +54,7 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from repro.core.reorder import mars_order_host
 from repro.kvcache.placement import row_group_of
 from repro.kvcache.pool import BlockPool, LINES_PER_BLOCK
 from repro.kvcache.prefix import PrefixCache
@@ -160,17 +162,12 @@ class TierStats(StatGroup):
 def promotion_order(group_ids: Sequence[int]) -> list[int]:
     """MARS emission order for a promotion batch, keyed by destination
     row group: writes grouped by row group, groups in first-arrival
-    order, FIFO within a group — the numpy rendering of
-    ``core.reorder.mars_order`` (tested equivalent against it).
+    order, FIFO within a group — ``core.reorder.mars_order_host``.
 
     >>> promotion_order([3, 1, 3, 1, 2])
     [0, 2, 1, 3, 4]
     """
-    first: dict[int, int] = {}
-    for i, g in enumerate(group_ids):
-        first.setdefault(g, i)
-    return sorted(range(len(group_ids)),
-                  key=lambda i: (first[group_ids[i]], i))
+    return mars_order_host(group_ids).tolist()
 
 
 def _key_tag(key: tuple) -> str:

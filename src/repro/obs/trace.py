@@ -121,11 +121,12 @@ class span:
     on the same clock as the device's operations, with ``fields`` as the
     event's stats; otherwise it costs about a microsecond.  Given a
     ``TraceLog`` it also records the JSONL span (``TraceLog.span``).
-    Yields the span's field dict: with a log, the JSONL event's (fields
-    added inside the region land in the JSONL line only), else a
-    scratch dict.
+    Yields the span's field dict: with a log, the JSONL event's, else a
+    scratch dict.  Fields added to it inside the region land in the JSONL
+    line and, while a profiler trace is being taken, among the profiler
+    event's stats.
     """
-    __slots__ = ("_ann", "_log", "_fields")
+    __slots__ = ("_ann", "_log", "_fields", "_n0")
 
     def __init__(self, name: str, log: Optional[TraceLog] = None,
                  **fields):
@@ -135,12 +136,16 @@ class span:
 
     def __enter__(self) -> dict:
         self._ann.__enter__()
-        if self._log is None:
-            return self._fields
-        return self._log.__enter__()
+        if self._log is not None:
+            self._fields = self._log.__enter__()
+        self._n0 = len(self._fields)
+        return self._fields
 
     def __exit__(self, *exc) -> None:
         try:
+            if len(self._fields) > self._n0 and self._ann.is_enabled():
+                added = list(self._fields.items())[self._n0:]
+                self._ann.set_metadata(**dict(added))
             if self._log is not None:
                 self._log.__exit__(*exc)
         finally:

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.reorder import mars_order_host
 from repro.kernels.paged_attention import ops
 from repro.kernels.paged_attention.ref import paged_attention_ref
 from repro.kvcache.pool import BlockPool
@@ -342,10 +343,13 @@ class ServeEngine:
         if self._sharded and self._lm is not None:
             shard_ids = [self._lm.backend.shard_of(s.sid)
                          for s in self.running]
-        with span("engine.lane_order", lanes=len(self.running)):
-            order = ops.batch_lane_order(
-                [s.table for s in self.running],
-                self.pool.cfg.blocks_per_group, shard_ids=shard_ids)
+        with span("engine.lane_order", lanes=len(self.running)) as sp:
+            keys = ops.lane_keys([s.table for s in self.running],
+                                 self.pool.cfg.blocks_per_group,
+                                 shard_ids=shard_ids)
+            order = mars_order_host(keys)
+            sp["groups"] = int(np.unique(keys).size)
+            sp["moved"] = int(np.count_nonzero(order != np.arange(order.size)))
         self.running = [self.running[i] for i in order]
 
         nxt = self._decode_lm() if self._lm is not None \

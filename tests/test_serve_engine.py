@@ -316,6 +316,63 @@ def test_engine_real_lm_forks_cow_and_diverge():
     assert eng.pool.num_live == 0
 
 
+def test_lane_order_runs_no_device_program(tmp_path):
+    """Decode lanes are ordered on the host: a step at 3 lanes after steps
+    at 4 (one power-of-two decode shape) compiles nothing, and the
+    ``engine.lane_order`` profiler event carries ``groups`` and ``moved``
+    — those of ``mars_order`` over the step's lane keys."""
+    import glob
+    import jax
+    from repro.core.reorder import mars_order
+    from repro.kernels.paged_attention import ops
+
+    jax.clear_caches()       # earlier tests may have compiled any lane count
+    eng, cfg, _ = _lm_engine(max_lanes=4)
+    rng = np.random.default_rng(0)
+    # 4-token prompts, so every step fits one page; two samples of the
+    # first request share a row group, the third request ends first
+    for i, (n_samples, max_new) in enumerate([(2, 5), (1, 5), (1, 3)]):
+        eng.submit(Request(
+            rid=i, prompt=tuple(int(t) for t in rng.integers(1, cfg.vocab, 4)),
+            arrival=i * 1e-3, max_new=max_new, n_samples=n_samples))
+    for _ in range(3):       # prefill, then two decodes at 4 lanes
+        assert eng.step() == 4
+    assert [s.rid for s in eng.running] == [0, 0, 1]
+    # an arrival order the lane order has to move: the samples apart
+    eng.running = [eng.running[i] for i in (0, 2, 1)]
+    keys = ops.lane_keys([s.table for s in eng.running],
+                         eng.pool.cfg.blocks_per_group)
+    lanes = list(eng.running)
+
+    compiles = []
+
+    def on_duration(event, secs, **kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(secs)
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert eng.step() == 3
+    finally:
+        jax.profiler.stop_trace()
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert compiles == []
+    # the reference runs only now: its own programs are not the step's
+    want = np.asarray(mars_order(keys))
+    want_fields = {"lanes": 3, "groups": len(set(keys.tolist())),
+                   "moved": int(np.count_nonzero(want != np.arange(3)))}
+    assert want_fields == {"lanes": 3, "groups": 2, "moved": 2}
+    assert eng.running == [lanes[i] for i in want]
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    fields, = [dict(e.stats) for plane in pd.planes
+               if plane.name.startswith("/host:")
+               for line in plane.lines for e in line.events
+               if e.name == "engine.lane_order"]
+    assert fields == want_fields
+
+
 @pytest.mark.parametrize("decode_mode", ["gather", "kernel"])
 def test_token_accounting_identical_across_decode_modes(decode_mode):
     """Regression pin for the prefill/decode token split: both decode
